@@ -199,7 +199,7 @@ def sharded_smoke_child(n: int = 96) -> list[str]:
     from jax.sharding import Mesh
     from repro.core import binning
     from repro.kernels.octent import sharded
-    from repro.runtime.sharding_compat import set_mesh
+    from jax import set_mesh
 
     assert len(jax.devices()) >= 8, (
         "sharded smoke needs 8 host devices; run benchmarks/search_speedup "

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
-
 
 def _kernel(mask_ref, a_ref, b_ref, out_ref, acc_ref, *, n_k: int):
     mi = pl.program_id(0)
@@ -66,7 +64,7 @@ def masked_matmul(a: jnp.ndarray, b: jnp.ndarray, mask: jnp.ndarray,
         functools.partial(_kernel, n_k=n_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="masked_matmul",

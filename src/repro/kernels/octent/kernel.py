@@ -7,9 +7,9 @@ ops. This kernel is the Query Transmitter of Fig. 6(a) as one pass: each
 grid step pulls a ``bq``-voxel tile of packed coordinates into VMEM,
 generates all K offset queries **in-register** (broadcast adds over the
 static offset list), Morton-encodes them with the same shift/mask ladder
-the ASIC wires into PNELUT, and resolves them against the VMEM-resident
-block directory + compacted banked table with two in-register binary
-searches. The kmap tile is written straight to the output block — no
+the ASIC wires into PNELUT, and resolves them against the SMEM-resident
+block directory + compacted banked table with two windowed scans (below).
+The kmap tile is written straight to the output block — no
 query tensor, no bkey array, no searchsorted intermediate ever exists in
 HBM (jaxpr-audited in tests/test_mapsearch.py).
 
@@ -24,14 +24,22 @@ Table layout (built sort-free by kernels/octent/ops.build_query_table):
     banks would strobe. ``tval`` holds the voxel index per slot.
 
 Searching the *compacted* table instead of direct-addressing the dense
-(max_blocks * 4096) one trades log2(N) in-register steps for a table that
-actually fits VMEM (4N bytes vs 16 KiB per block) — the dense table stays
+(max_blocks * 4096) one trades a search for a table that actually fits
+on chip (4N bytes vs 16 KiB per block) — the dense table stays
 the XLA oracle's representation.
 
-The two binary searches index VMEM-resident int32 vectors with computed
-(K, bq) index tiles (``jnp.take``); on hosts without the Mosaic dynamic-
-gather lowering the wrapper runs under the Pallas interpreter, mirroring
-the spconv_gemm kernels (`ops.hardware_impl`).
+Mosaic lowers no per-lane dynamic gather out of a VMEM vector, so the
+two lookups never index the tables with a query tile. The tables sit in
+SMEM (scalar-prefetched) and each lookup is a compare-and-select scan:
+a scalar binary search narrows the table to the window of keys the
+tile's live queries can hit, ``[lower_bound(kmin), upper_bound(kmax))``,
+and every entry of that window is broadcast against the whole (K, bq)
+query tile on the VPU. The scan runs from the top of the window down, so
+under duplicate keys the lowest matching slot wins — exactly the
+``searchsorted`` lower bound of the oracle (ref.py), hence bit-identical
+kmaps. SMEM (1 MiB on v5e) holds the whole directory and table, 12 bytes
+per slot when ``max_blocks`` equals the voxel count: that caps one
+search at about 85k voxels (DESIGN.md §3).
 """
 from __future__ import annotations
 
@@ -43,47 +51,55 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import morton
-from repro.kernels.pallas_compat import tpu_compiler_params
 
 #: lane width of the table arrays (tkey/tval/ublocks are padded to this)
 LANE = 128
 
 
-def _lower_bound(arr: jnp.ndarray, key: jnp.ndarray, size: int,
-                 hi0: jnp.ndarray, steps: int) -> jnp.ndarray:
-    """Vectorized first-position-not-less-than over a sorted 1D array.
-
-    Fixed ``steps`` iterations (the grid has no data-dependent trip
-    counts); each step gathers one probe per query lane. ``hi0`` bounds
-    the live prefix of ``arr`` (entries beyond it are sentinel-padded).
-    """
-    lo = jnp.zeros(key.shape, jnp.int32)
-    hi = jnp.broadcast_to(hi0, key.shape).astype(jnp.int32)
-    for _ in range(steps):
-        cont = lo < hi
-        mid = (lo + hi) >> 1
-        mv = jnp.take(arr, jnp.minimum(mid, size - 1))
-        right = cont & (mv < key)
-        lo = jnp.where(right, mid + 1, lo)
-        hi = jnp.where(cont & ~right, mid, hi)
-    return lo
+def _lower_bound(arr_ref, key, lo, hi, steps: int):
+    """First ``j`` in ``[lo, hi)`` with ``arr_ref[j] >= key`` (``hi`` if
+    none): a scalar binary search over a sorted SMEM array, ``steps``
+    fixed iterations (enough for the array's length)."""
+    def step(_, c):
+        lo, hi = c
+        mid = (lo + hi) // 2
+        right = (lo < hi) & (arr_ref[jnp.minimum(mid, hi - 1)] < key)
+        return (jnp.where(right, mid + 1, lo),
+                jnp.where((lo < hi) & ~right, mid, hi))
+    return jax.lax.fori_loop(0, steps, step, (lo, hi))[0]
 
 
-def _octent_kernel(nblk_ref, q_ref, offs_ref, ub_ref, tkey_ref, tval_ref,
-                   out_ref, *, grid_bits: int, batch_bits: int,
-                   max_blocks: int, nb_steps: int, nt_steps: int):
-    k = out_ref.shape[0]
-    ub = ub_ref[0]
-    tkey = tkey_ref[0]
-    tval = tval_ref[0]
+def _match(key_ref, val_ref, keys, live, lo, hi, steps: int):
+    """Per query of the tile, the value of the first table slot whose key
+    equals it (-1 if none) — the compare-and-select scan of the module
+    doc. ``val_ref`` None yields the slot index itself. Only ``live``
+    queries narrow the scanned window; the others may still match inside
+    it and are masked by the caller."""
+    big = jnp.iinfo(jnp.int32).max
+    kmin = jnp.min(jnp.where(live, keys, big))
+    kmax = jnp.max(jnp.where(live, keys, -1))
+    w_lo = _lower_bound(key_ref, kmin, lo, hi, steps)
+    w_hi = _lower_bound(key_ref, kmax + 1, w_lo, hi, steps)
+
+    def scan(t, acc):
+        j = w_hi - 1 - t
+        val = j if val_ref is None else val_ref[j]
+        return jnp.where(keys == key_ref[j], val, acc)
+
+    return jax.lax.fori_loop(0, w_hi - w_lo, scan,
+                             jnp.full(keys.shape, -1, jnp.int32))
+
+
+def _octent_kernel(nblk_ref, ub_ref, tkey_ref, tval_ref, q_ref, offs_ref,
+                   out_ref, *, grid_bits: int, max_blocks: int, n_t: int):
     n_blocks = jnp.minimum(nblk_ref[0], max_blocks)
 
     # -- query generation, in-register: (K, bq) per coordinate channel
-    x = q_ref[0][None, :] + offs_ref[:, 0][:, None]
-    y = q_ref[1][None, :] + offs_ref[:, 1][:, None]
-    z = q_ref[2][None, :] + offs_ref[:, 2][:, None]
-    bt = jnp.broadcast_to(q_ref[3][None, :], (k, x.shape[1]))
-    v = q_ref[4][None, :] != 0
+    x = q_ref[0:1, :] + offs_ref[:, 0:1]
+    y = q_ref[1:2, :] + offs_ref[:, 1:2]
+    z = q_ref[2:3, :] + offs_ref[:, 2:3]
+    bt = q_ref[3:4, :]
+    v = q_ref[4:5, :] != 0
 
     limit = (1 << grid_bits) * morton.BLOCK_SIZE
     inb = ((x >= 0) & (x < limit) & (y >= 0) & (y < limit)
@@ -104,17 +120,15 @@ def _octent_kernel(nblk_ref, q_ref, offs_ref, ub_ref, tkey_ref, tval_ref,
     bank, row = morton.bank_and_row(phi)
 
     # -- stage 1: block key -> rank in the directory
-    rank = _lower_bound(ub, bkey, ub.shape[0], n_blocks, nb_steps)
-    hit_b = ((rank < n_blocks)
-             & (jnp.take(ub, jnp.minimum(rank, ub.shape[0] - 1)) == bkey))
+    rank = _match(ub_ref, None, bkey, inb, 0, n_blocks,
+                  max(max_blocks.bit_length(), 1))
+    hit_b = inb & (rank >= 0)
 
     # -- stage 2: (rank, bank, row) -> voxel via the compacted banked table
     key2 = rank * morton.TABLE_SIZE + bank * morton.BANK_ROWS + row
-    n_t = tkey.shape[0]
-    pos = _lower_bound(tkey, key2, n_t, n_t, nt_steps)
-    pos_c = jnp.minimum(pos, n_t - 1)
-    hit = hit_b & inb & (jnp.take(tkey, pos_c) == key2)
-    out_ref[...] = jnp.where(hit, jnp.take(tval, pos_c), -1)
+    val = _match(tkey_ref, tval_ref, key2, hit_b, 0, n_t,
+                 max(n_t.bit_length(), 1))
+    out_ref[...] = jnp.where(hit_b, val, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("grid_bits", "batch_bits", "bq",
@@ -134,35 +148,27 @@ def octent_query(qpack: jnp.ndarray, offsets: jnp.ndarray,
     assert five == 5 and n_pad % bq == 0, (qpack.shape, bq)
     k = offsets.shape[0]
     max_blocks = ublocks.shape[0]
-    mb_pad = -(-max_blocks // LANE) * LANE
-    ub = jnp.pad(ublocks, (0, mb_pad - max_blocks),
-                 constant_values=jnp.iinfo(jnp.int32).max)
     n_t = tkey.shape[0]
     assert n_t % LANE == 0 and tval.shape[0] == n_t, (n_t, tval.shape)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=4,
         grid=(n_pad // bq,),
         in_specs=[
-            pl.BlockSpec((5, bq), lambda i, nblk: (0, i)),
-            pl.BlockSpec((k, 3), lambda i, nblk: (0, 0)),
-            pl.BlockSpec((1, mb_pad), lambda i, nblk: (0, 0)),
-            pl.BlockSpec((1, n_t), lambda i, nblk: (0, 0)),
-            pl.BlockSpec((1, n_t), lambda i, nblk: (0, 0)),
+            pl.BlockSpec((5, bq), lambda i, *pf: (0, i)),
+            pl.BlockSpec((k, 3), lambda i, *pf: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((k, bq), lambda i, nblk: (0, i)),
+        out_specs=pl.BlockSpec((k, bq), lambda i, *pf: (0, i)),
     )
-    kernel = functools.partial(
-        _octent_kernel, grid_bits=grid_bits, batch_bits=batch_bits,
-        max_blocks=max_blocks, nb_steps=max(mb_pad.bit_length(), 1),
-        nt_steps=max(n_t.bit_length(), 1))
+    kernel = functools.partial(_octent_kernel, grid_bits=grid_bits,
+                               max_blocks=max_blocks, n_t=n_t)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((k, n_pad), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="octent_query",
-    )(jnp.atleast_1d(n_blocks).astype(jnp.int32), qpack, offsets,
-      ub.reshape(1, mb_pad), tkey.reshape(1, n_t), tval.reshape(1, n_t))
+    )(jnp.atleast_1d(n_blocks).astype(jnp.int32), ublocks, tkey, tval,
+      qpack, offsets)

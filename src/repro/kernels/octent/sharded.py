@@ -41,18 +41,20 @@ search *structure* it emits is distributed.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import get_abstract_mesh
 
 from repro.core import mapsearch, morton
 from repro.kernels.octent.kernel import LANE
 from repro.kernels.octent.ref import encode_queries
 from repro.runtime import sharding
-from repro.runtime.sharding_compat import get_abstract_mesh, shard_map
 
 
 class ShardedQueryTable(NamedTuple):
@@ -85,13 +87,15 @@ def _pad_sorted(x: jnp.ndarray, size: int, fill) -> jnp.ndarray:
 def _pin(x: jnp.ndarray, mesh, spec: P) -> jnp.ndarray:
     """Lay ``x`` out sharded: constraint under trace, device_put eagerly.
 
-    Off-trace placement needs a physical mesh (abstract meshes carry no
-    devices); without one the array stays where it is — shard_map's
-    in_specs still distribute it at query time.
+    Off-trace placement needs a physical mesh: an abstract one is
+    resolved to the concrete mesh of the context (sharding.concrete_mesh);
+    without one the array stays where it is — shard_map's in_specs still
+    distribute it at query time.
     """
     if isinstance(x, jax.core.Tracer):
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    if getattr(mesh, "devices", None) is not None:
+    mesh = sharding.concrete_mesh(mesh)
+    if mesh is not None:
         return jax.device_put(x, NamedSharding(mesh, spec))
     return x
 
@@ -101,7 +105,7 @@ def _resolve_mesh(mesh, axes):
     if mesh is None or mesh.empty:
         raise ValueError(
             "sharded OCTENT search needs an active device mesh — enter one "
-            "with runtime.sharding_compat.set_mesh (or pass mesh=), or use "
+            "with jax.set_mesh (or pass mesh=), or use "
             "a single-device impl ('ref'/'pallas'/'xla')")
     axes = tuple(axes) if axes is not None else sharding.blockkey_axes(mesh)
     if not axes:
@@ -218,6 +222,23 @@ def _partial_query(ub_loc, rank_base, tkey_loc, tval_loc,
     return kmap
 
 
+@functools.partial(jax.jit, static_argnames=("mesh", "axes", "grid_bits",
+                                             "return_partials"))
+def _query_on_mesh(ub, rank_base, tkey, tval, coords, batch, valid, offsets,
+                   *, mesh, axes, grid_bits, return_partials):
+    """The shard_map'd query, jitted so an eager call compiles once per
+    (mesh, shapes) and not once per call."""
+    ax = axes if len(axes) > 1 else axes[0]
+    fn = shard_map(
+        functools.partial(_partial_query, grid_bits=grid_bits, axes=axes,
+                          return_partials=return_partials),
+        mesh=mesh,
+        in_specs=(P(ax), P(ax), P(ax), P(ax), P(), P(), P(), P()),
+        out_specs=(P(), P(ax), P(ax)) if return_partials else P(),
+        check_vma=False)
+    return fn(ub, rank_base, tkey, tval, coords, batch, valid, offsets)
+
+
 def octent_query_sharded(coords: jnp.ndarray, batch: jnp.ndarray,
                          valid: jnp.ndarray, offsets: jnp.ndarray,
                          sqt: ShardedQueryTable, *, grid_bits: int = 7,
@@ -236,17 +257,11 @@ def octent_query_sharded(coords: jnp.ndarray, batch: jnp.ndarray,
     mesh, axes = _resolve_mesh(mesh, sqt.axes)
     s = sqt.n_shards
     rank_base = jnp.arange(s, dtype=jnp.int32) * (sqt.ublocks.shape[0] // s)
-    ax = axes if len(axes) > 1 else axes[0]
-    out_specs = (P(), P(ax), P(ax)) if return_partials else P()
-    fn = shard_map(
-        lambda ub, rb, tk, tv, c, b, v, o: _partial_query(
-            ub, rb, tk, tv, c, b, v, o, grid_bits=grid_bits,
-            axes=axes, return_partials=return_partials),
-        mesh=mesh,
-        in_specs=(P(ax), P(ax), P(ax), P(ax), P(), P(), P(), P()),
-        out_specs=out_specs, check_vma=False)
-    out = fn(sqt.ublocks, rank_base, sqt.tkey, sqt.tval, coords,
-             batch.astype(jnp.int32), valid, offsets.astype(jnp.int32))
+    out = _query_on_mesh(sqt.ublocks, rank_base, sqt.tkey, sqt.tval, coords,
+                         batch.astype(jnp.int32), valid,
+                         offsets.astype(jnp.int32), mesh=mesh, axes=axes,
+                         grid_bits=grid_bits,
+                         return_partials=return_partials)
     nb = jnp.asarray(sqt.n_blocks, jnp.int32)
     if return_partials:
         kmap, pranks, partials = out

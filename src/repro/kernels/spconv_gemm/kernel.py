@@ -21,11 +21,11 @@ Two entry points (DESIGN.md §6):
     returns (M_pad, Cout) partial products for an external scatter-add.
     The original materialized baseline.
   * :func:`spconv_gemm_fused` — the default execution backend
-    (core/plan.py). Takes the *full* feature array plus scalar-prefetched
-    gather indices and per-tile run metadata; rows are pulled straight out
-    of HBM by double-buffered DMAs (tile r+1's copies fly while tile r
-    computes), C_in is processed in bk-sized blocks with an f32 VMEM
-    accumulator, and partial sums are scatter-added *inside the kernel*
+    (core/plan.py). Takes the *full* feature array plus gather indices
+    (streamed per tile into SMEM) and per-tile run metadata; rows are
+    pulled straight out of HBM by double-buffered DMAs (tile r+1's copies
+    fly while tile r computes), C_in is processed in bk-sized blocks with
+    an f32 VMEM accumulator, and partial sums are scatter-added *inside the kernel*
     into the output block — neither the (M_pad, C_in) gathered copy nor
     the (M_pad, C_out) partial-product array ever exists in HBM.
 """
@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
 
 # Contiguity metadata granularity: gather runs are detected per GRP-slot
 # group at plan-build time (ops.build_tap_tiles); a contiguous group is one
@@ -46,6 +45,15 @@ from repro.kernels.pallas_compat import tpu_compiler_params
 # bm-row DMA. Must divide bm (ops asserts); bm/GRP <= 32 so the per-tile
 # masks fit int32.
 GRP = 8
+
+#: lane width: feature rows are DMA'd in whole 128-lane tiles
+LANE = 128
+
+#: MXU precision of the fused kernel's two dots. Float32 operands are
+#: contracted at fp32 (HIGHEST: multi-pass bf16 on the MXU), so the
+#: in-kernel one-hot scatter moves the f32 partial sums without rounding
+#: them to bf16 and the layer matches the f32 oracle (DESIGN.md §6).
+F32 = jax.lax.Precision.HIGHEST
 
 
 def _kernel(tile_tap_ref, tile_nz_ref, lhs_ref, w_ref, out_ref):
@@ -92,18 +100,20 @@ def spconv_gemm(lhs: jnp.ndarray, weights: jnp.ndarray,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, c_out), lhs.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="spconv_gemm",
     )(tile_tap, tile_nz, lhs, weights)
 
 
-def _row_dmas(do, gidx_ref, tile_run_ref, grp_skip_ref, grp_contig_ref,
-              feats_ref, rows_ref, sem, i2, k2, slot, *, bm, bk, grp):
-    """Start or wait the gather DMAs of tile ``i2``, Cin-block ``k2`` into
-    buffer ``slot``. The wait path mirrors the start path exactly (same
-    descriptors on the same semaphore), so starts and waits always balance.
+def _row_dmas(do, gidx_ref, run, skip, contig, feats_ref, rows_ref, sem,
+              k2, slot, *, bm, grp):
+    """Start or wait the gather DMAs of one tile's Cin-block ``k2`` into
+    buffer ``slot``. ``gidx_ref`` is that tile's (1, 1, bm) SMEM block of
+    gather indices; ``run`` / ``skip`` / ``contig`` its run metadata. The
+    wait path mirrors the start path exactly (same descriptors on the same
+    semaphore), so starts and waits always balance.
 
     Copy granularity is chosen from the plan-build run metadata: a
     whole-tile run is one bm-row strided copy; a contiguous GRP-slot group
@@ -111,41 +121,37 @@ def _row_dmas(do, gidx_ref, tile_run_ref, grp_skip_ref, grp_contig_ref,
     Groups with no valid slot are skipped entirely — their (garbage) rows
     are dropped by the in-kernel scatter, so they cost no bandwidth at all.
     """
-    base = i2 * bm
-    col = k2 * bk
-
     def cp(nrows, src_row, dst_row):
         c = pltpu.make_async_copy(
-            feats_ref.at[pl.ds(src_row, nrows), pl.ds(col, bk)],
+            feats_ref.at[k2, pl.ds(src_row, nrows)],
             rows_ref.at[slot, pl.ds(dst_row, nrows)],
             sem.at[slot])
         c.start() if do == "start" else c.wait()
 
-    run = tile_run_ref[i2] != 0
-
     @pl.when(run)
     def _whole_tile():
-        cp(bm, gidx_ref[base], 0)
+        cp(bm, gidx_ref[0, 0, 0], 0)
 
     @pl.when(~run)
     def _grouped():
         for g in range(bm // grp):
-            live = ((grp_skip_ref[i2] >> g) & 1) == 0
-            contig = ((grp_contig_ref[i2] >> g) & 1) != 0
+            live = ((skip >> g) & 1) == 0
+            is_contig = ((contig >> g) & 1) != 0
 
-            @pl.when(live & contig)
+            @pl.when(live & is_contig)
             def _one_copy(g=g):
-                cp(grp, gidx_ref[base + g * grp], g * grp)
+                cp(grp, gidx_ref[0, 0, g * grp], g * grp)
 
-            @pl.when(live & ~contig)
+            @pl.when(live & ~is_contig)
             def _per_row(g=g):
                 for r in range(grp):
-                    cp(1, gidx_ref[base + g * grp + r], g * grp + r)
+                    cp(1, gidx_ref[0, 0, g * grp + r], g * grp + r)
 
 
 def _os_kernel(tile_tap_ref, tile_nz_ref, tile_bk_ref, tile_ob_ref,
                tile_first_ref, tile_last_ref, tile_run_ref, grp_skip_ref,
-               grp_contig_ref, gidx_ref, scat_ref, feats_ref, w_ref, *rest,
+               grp_contig_ref, gcur_ref, gnxt_ref, scat_row_ref,
+               scat_col_ref, feats_ref, w_ref, *rest,
                bm: int, bn: int, bo: int, grp: int, epilogue: bool):
     if epilogue:
         (scale_ref, shift_ref, valid_ref, out_ref, nz_ref,
@@ -162,40 +168,47 @@ def _os_kernel(tile_tap_ref, tile_nz_ref, tile_bk_ref, tile_ob_ref,
     s = i * n_k + k                   # DMA step: one rows-block per (i, k)
     slot = s % 2
 
-    dmas = functools.partial(
-        _row_dmas, gidx_ref=gidx_ref, tile_run_ref=tile_run_ref,
-        grp_skip_ref=grp_skip_ref, grp_contig_ref=grp_contig_ref,
-        feats_ref=feats_ref, rows_ref=rows_ref, sem=sem,
-        bm=bm, bk=bk, grp=grp)
+    def dmas(do, gidx_ref, i2, k2, slot):
+        _row_dmas(do, gidx_ref, tile_run_ref[i2] != 0, grp_skip_ref[i2],
+                  grp_contig_ref[i2], feats_ref, rows_ref, sem, k2, slot,
+                  bm=bm, grp=grp)
 
     nz = tile_nz_ref[i] != 0
     # Cin-block grain SPAC (DESIGN.md §14): a dead (tile, Cin-block) pair —
     # every gathered row's bk-slice is exactly zero — costs neither the
-    # gather DMA nor the MAC. tile_bk_ref[i, k] <= tile_nz_ref[i] by
+    # gather DMA nor the MAC. tile_bk_ref[i * n_k + k] <= tile_nz_ref[i] by
     # construction (ops.tile_block_liveness), so a live block implies a
     # live tile.
-    blk = tile_bk_ref[i, k] != 0
+    blk = tile_bk_ref[s] != 0
 
     # -- gather stage, double-buffered: step s+1's copies are started before
     # step s's compute, so the next tile/Cin-block fetch overlaps the MACs.
-    # Dead blocks start no copies and wait on none; slot parity stays
-    # consistent because start and wait are gated by the same tile_bk entry.
+    # The gather indices of this tile and the next arrive as pipelined SMEM
+    # blocks (gcur / gnxt), never as one whole-array prefetch. Dead blocks
+    # start no copies and wait on none; slot parity stays consistent
+    # because start and wait are gated by the same tile_bk entry.
     @pl.when(j == 0)
     def _dma_schedule():
         @pl.when((s == 0) & blk)
         def _warmup():
-            dmas(do="start", i2=i, k2=k, slot=slot)
+            dmas("start", gcur_ref, i, k, slot)
 
-        s1 = s + 1
-        i1 = jnp.minimum(s1 // n_k, n_m - 1)
+        s1 = jnp.minimum(s + 1, n_m * n_k - 1)
+        more = s + 1 < n_m * n_k
+        same_tile = k + 1 < n_k
 
-        @pl.when((s1 < n_m * n_k) & (tile_bk_ref[i1, s1 % n_k] != 0))
-        def _prefetch_next():
-            dmas(do="start", i2=i1, k2=s1 % n_k, slot=s1 % 2)
+        @pl.when(more & same_tile & (tile_bk_ref[s1] != 0))
+        def _prefetch_next_block():
+            dmas("start", gcur_ref, i, k + 1, 1 - slot)
+
+        @pl.when(more & ~same_tile & (tile_bk_ref[s1] != 0))
+        def _prefetch_next_tile():
+            dmas("start", gnxt_ref, jnp.minimum(i + 1, n_m - 1), 0,
+                 1 - slot)
 
         @pl.when(blk)
         def _arrived():
-            dmas(do="wait", i2=i, k2=k, slot=slot)
+            dmas("wait", gcur_ref, i, k, slot)
 
     # -- MAC stage: (bm, bk) @ (bk, bn) MXU tiles, f32 accumulation over the
     # Cin blocks in a VMEM scratch (never written back to HBM). A live tile
@@ -206,7 +219,7 @@ def _os_kernel(tile_tap_ref, tile_nz_ref, tile_bk_ref, tile_ob_ref,
     def _compute():
         partial = jax.lax.dot_general(
             rows_ref[slot], w_ref[0],
-            (((1,), (0,)), ((), ())),
+            (((1,), (0,)), ((), ())), precision=F32,
             preferred_element_type=jnp.float32)
 
         @pl.when(k == 0)
@@ -240,15 +253,21 @@ def _os_kernel(tile_tap_ref, tile_nz_ref, tile_bk_ref, tile_ob_ref,
             # local row of each slot inside this output block; slots whose
             # target lies outside (padding and SPAC-elided maps) select no
             # row of the one-hot matrix and are masked before the matmul so
-            # uninitialized gather rows can never poison the output.
-            local = scat_ref[0] - tile_ob_ref[i] * bo
+            # uninitialized gather rows can never poison the output. The
+            # targets arrive twice, as a (1, bm) row for the one-hot matrix
+            # and as a (bm, 1) column for the row mask: Mosaic does not
+            # relayout a lane vector into a sublane one.
+            base = tile_ob_ref[i] * bo
+            local = scat_row_ref[0] - base
             inb = (local >= 0) & (local < bo)
             sel = (jax.lax.broadcasted_iota(jnp.int32, (bo, bm), 0)
-                   == local[None, :]) & inb[None, :]
+                   == local) & inb
+            local_c = scat_col_ref[...] - base
+            inb_c = (local_c >= 0) & (local_c < bo)
             contrib = jax.lax.dot_general(
                 sel.astype(jnp.float32),
-                jnp.where(inb[:, None], acc_ref[...], 0.0),
-                (((1,), (0,)), ((), ())),
+                jnp.where(inb_c, acc_ref[...], 0.0),
+                (((1,), (0,)), ((), ())), precision=F32,
                 preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
             @pl.when(first)
@@ -270,15 +289,15 @@ def _os_kernel(tile_tap_ref, tile_nz_ref, tile_bk_ref, tile_ob_ref,
         if epilogue:
             @pl.when(tile_last_ref[i] != 0)
             def _bn_relu():
-                y = (out_ref[...].astype(jnp.float32) * scale_ref[0][None, :]
-                     + shift_ref[0][None, :])
+                y = (out_ref[...].astype(jnp.float32) * scale_ref[...]
+                     + shift_ref[...])
                 y = jnp.where(valid_ref[...] != 0, jnp.maximum(y, 0.0), 0.0)
                 yc = y.astype(out_ref.dtype)
                 out_ref[...] = yc
-                n_gr = nz_ref.shape[-1]
-                cols = [(yc[:, g * bn:(g + 1) * bn] != 0).any(
-                    axis=1, keepdims=True) for g in range(n_gr)]
-                nz_ref[...] = jnp.concatenate(cols, axis=1).astype(jnp.int32)
+                for g in range(nz_ref.shape[-1]):
+                    nz_ref[:, g:g + 1] = jnp.max(
+                        (yc[:, g * bn:(g + 1) * bn] != 0).astype(jnp.int32),
+                        axis=1, keepdims=True)
 
 
 @functools.partial(
@@ -318,6 +337,14 @@ def spconv_gemm_fused(feats: jnp.ndarray, weights: jnp.ndarray,
     each block run's closing tile) and returns ``(out, nz)`` where nz
     (n_out_pad, Cout/bn) int32 is the next layer's per-(row, bn-group)
     liveness — emitted in-kernel, no HBM re-sweep (DESIGN.md §14).
+
+    The kernel reads features as (n_k, N, bk) Cin-block slabs, one DMA per
+    row run. Mosaic slices an HBM row at any offset only out of a slab one
+    lane tile (LANE) wide, so a ``bk`` that is all of Cin or a LANE
+    multiple is re-blocked to LANE here: Cin is zero-padded up to a LANE
+    multiple (zero columns add exact zeros) and each ``tile_bk_nz`` column
+    is repeated over its LANE sub-blocks. Any other ``bk`` is kept as it
+    is, which only the interpreter accepts.
     """
     _, c_in = feats.shape
     k_taps, _, c_out = weights.shape
@@ -325,6 +352,16 @@ def spconv_gemm_fused(feats: jnp.ndarray, weights: jnp.ndarray,
     bk = c_in if bk is None else bk
     assert m % bm == 0 and c_out % bn == 0, (m, bm, c_out, bn)
     assert c_in % bk == 0, (c_in, bk)
+    if tile_bk_nz is None:
+        tile_bk_nz = jnp.repeat(tile_nz[:, None], c_in // bk, axis=1)
+    if bk == c_in or bk % LANE == 0:
+        pad = -c_in % LANE
+        if pad:
+            feats = jnp.pad(feats, ((0, 0), (0, pad)))
+            weights = jnp.pad(weights, ((0, 0), (0, pad), (0, 0)))
+        tile_bk_nz = jnp.repeat(tile_bk_nz, (bk + pad) // LANE, axis=1)
+        c_in, bk = c_in + pad, LANE
+    assert interpret or bk == LANE, (c_in, bk)
     assert n_out_pad % bo == 0, (n_out_pad, bo)
     grp = GRP if bm % GRP == 0 else bm
     assert bm // grp <= 32, (bm, grp)
@@ -332,28 +369,35 @@ def spconv_gemm_fused(feats: jnp.ndarray, weights: jnp.ndarray,
     for t in (tile_tap, tile_nz, tile_ob, tile_first, tile_run, grp_skip,
               grp_contig):
         assert t.shape[0] == n_m, (t.shape, n_m)
-    if tile_bk_nz is None:
-        tile_bk_nz = jnp.repeat(tile_nz[:, None], n_k, axis=1)
     assert tile_bk_nz.shape == (n_m, n_k), (tile_bk_nz.shape, n_m, n_k)
     if tile_last is None:
         tile_last = jnp.concatenate(
             [(tile_ob[1:] != tile_ob[:-1]).astype(jnp.int32),
              jnp.ones(1, jnp.int32)])
 
-    # index maps see the 10 scalar-prefetch refs appended; only tap/ob used
+    # index maps see the 9 scalar-prefetch refs appended; only tap/ob used
     ob_map = lambda i, k, j, tap, nz, bk_nz, ob, *pf: (ob[i], 0)
+    smem_tile = lambda f: pl.BlockSpec((1, 1, bm), f,
+                                       memory_space=pltpu.SMEM)
     in_specs = [
-        # per-slot output targets as a VMEM row per tile (vector read;
-        # the scalar-prefetch SMEM copy only feeds address computation)
-        pl.BlockSpec((1, bm), lambda i, k, j, *pf: (i, 0)),
-        # full feature array, un-blocked: rows are DMA'd on demand
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        # gather indices of this tile and of the next one, streamed as
+        # per-tile SMEM blocks: the DMA descriptors read them as scalars
+        smem_tile(lambda i, k, j, *pf: (i, 0, 0)),
+        smem_tile(lambda i, k, j, *pf: (jnp.minimum(i + 1, n_m - 1), 0, 0)),
+        # per-slot output targets of this tile, as a row and as a column
+        pl.BlockSpec((1, 1, bm), lambda i, k, j, *pf: (i, 0, 0)),
+        pl.BlockSpec((bm, 1), lambda i, k, j, *pf: (i, 0)),
+        # full feature slabs, left in HBM: rows are DMA'd on demand
+        pl.BlockSpec(memory_space=pltpu.HBM),
         # weight block chosen by the prefetched tap id and the Cin block
         pl.BlockSpec((1, bk, bn), lambda i, k, j, tap, *pf: (tap[i], k, j)),
     ]
-    operands = [tile_tap, tile_nz, tile_bk_nz, tile_ob, tile_first,
-                tile_last, tile_run, grp_skip, grp_contig, gather_idx,
-                scatter_idx.reshape(n_m, bm), feats, weights]
+    gidx = gather_idx.reshape(n_m, 1, bm)
+    operands = [tile_tap, tile_nz, tile_bk_nz.reshape(-1), tile_ob,
+                tile_first, tile_last, tile_run, grp_skip, grp_contig,
+                gidx, gidx, scatter_idx.reshape(n_m, 1, bm),
+                scatter_idx.reshape(m, 1),
+                feats.reshape(-1, n_k, bk).transpose(1, 0, 2), weights]
     if epilogue:
         assert epi_scale is not None and epi_shift is not None \
             and epi_valid is not None
@@ -374,7 +418,7 @@ def spconv_gemm_fused(feats: jnp.ndarray, weights: jnp.ndarray,
         out_shape = jax.ShapeDtypeStruct((n_out_pad, c_out), feats.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=10,
+        num_scalar_prefetch=9,
         grid=(n_m, n_k, n_n),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -391,7 +435,7 @@ def spconv_gemm_fused(feats: jnp.ndarray, weights: jnp.ndarray,
         out_shape=out_shape,
         # rows / acc scratch and the output block are carried across grid
         # steps, so every dimension must execute in order
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="spconv_gemm_fused",

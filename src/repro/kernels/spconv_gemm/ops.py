@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from repro.core import rulebook as _rulebook
 from repro.core import sparsity as _sparsity
-from repro.kernels.spconv_gemm.kernel import (GRP, spconv_gemm,
+from repro.kernels.spconv_gemm.kernel import (GRP, LANE, spconv_gemm,
                                               spconv_gemm_fused)
 from repro.kernels.spconv_gemm.ref import (spconv_gemm_fused_ref,
                                            spconv_gemm_ref)
@@ -314,12 +314,13 @@ def pick_bk(c_in: int, *, bm: int, bn: int, bo: int, c_out: int,
     §6 working set in budget: double-buffered rows (2*bm*bk), the weight
     block (bk*bn), the f32 accumulator (bm*c_out) and the resident output
     block (bo*c_out). Caps bk at 512 (the old whole-Cin residency limit) so
-    wide backbones stop relying on whole-Cin VMEM residency; falls back to
-    whole-Cin when nothing divides."""
+    wide backbones stop relying on whole-Cin VMEM residency; a block short
+    of all Cin must be a LANE multiple (the kernel DMAs whole lane tiles);
+    falls back to whole-Cin when nothing fits."""
     fixed = 4 * (bm * c_out + bo * c_out)
     for bk in sorted((d for d in range(1, c_in + 1) if c_in % d == 0),
                      reverse=True):
-        if bk > 512:
+        if bk > 512 or (bk != c_in and bk % LANE):
             continue
         if fixed + 4 * (2 * bm * bk + bk * bn) <= budget_bytes:
             return bk

@@ -27,11 +27,11 @@ import time
 import traceback
 
 import jax
+from jax import set_mesh
 
 from repro.configs import SHAPE_CELLS, cell_applicable, get_config, list_archs
 from repro.launch import hlo_analysis, shardings
 from repro.launch.mesh import make_production_mesh
-from repro.runtime.sharding_compat import set_mesh
 from repro.launch.train import make_train_step
 from repro.models import api
 from repro.optim import adamw

@@ -5,7 +5,8 @@ touches jax device state.
 """
 from __future__ import annotations
 
-from repro.runtime.sharding_compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -8,10 +8,10 @@ axis), so one rule table covers every architecture and both meshes.
 from __future__ import annotations
 
 import jax
+from jax import set_mesh
 from jax.sharding import NamedSharding
 
 from repro.runtime import sharding as rs
-from repro.runtime.sharding_compat import set_mesh
 
 # weight matrices whose LAST dim is the TP-sharded output features
 _LAST = {"wq", "wk", "wv", "w_gate", "w_up", "lm_head", "pred_head",

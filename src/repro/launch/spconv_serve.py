@@ -33,7 +33,7 @@ Robustness posture:
   * The ``batch`` fault site attacks batch assembly itself (retried
     once; a persistent failure isolates only that tick's requests).
 
-CLI (CPU-scale demo of the full path):
+CLI (a small demo of the full path; on a TPU it runs both Pallas kernels):
 
     PYTHONPATH=src python -m repro.launch.spconv_serve \
         --requests 12 --buckets 96,192 --health-json /tmp/health.json
@@ -118,9 +118,10 @@ class ServeEngine:
 
     Args:
       params, model_cfg: the served model (init once, serve many).
-      impl: primary rulebook-execution backend (default ``'ref'`` — the
-        deterministic CPU choice; ladder level 2 forces ``'ref'``
-        regardless).
+      impl: primary rulebook-execution backend (None: resolved per host
+        by ``spconv_gemm.ops.kernel_impl`` — the fused Pallas kernel on
+        TPU, ``'ref'`` elsewhere). Ladder level 2 forces ``'ref'``, a
+        documented degradation that the health counters record.
       queue: an :class:`~repro.runtime.admission.AdmissionQueue` (None:
         construct one from the flags with the model's grid contract).
       max_batch: requests drained per tick (None:
@@ -145,7 +146,8 @@ class ServeEngine:
     """
 
     def __init__(self, params, model_cfg: minkunet.MinkUNetConfig, *,
-                 impl: str = "ref", queue: admission.AdmissionQueue | None = None,
+                 impl: str | None = None,
+                 queue: admission.AdmissionQueue | None = None,
                  max_batch: int | None = None, clock=time.monotonic,
                  verify_cache: bool = False, recover_after: int = 2,
                  persist_dir: str | None = None):
@@ -495,7 +497,10 @@ def main() -> None:
                     help="comma-separated padding-bucket sizes "
                          "(default: REPRO_SERVE_BUCKETS)")
     ap.add_argument("--max-batch", type=int, default=None)
-    ap.add_argument("--impl", default="ref")
+    ap.add_argument("--impl", default="auto",
+                    help="rulebook-execution backend: auto (REPRO_KERNEL_IMPL "
+                         "/ the fused kernel on TPU, 'ref' elsewhere) | "
+                         "pallas | interpret | ref")
     ap.add_argument("--deadline-s", type=float, default=None)
     ap.add_argument("--health-json", default=None,
                     help="write the RuntimeHealth snapshot + serve stats "
@@ -505,6 +510,8 @@ def main() -> None:
                          "journal (default: REPRO_PERSIST_DIR; unset "
                          "disables persistence) — DESIGN.md §13")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     buckets = tuple(int(x) for x in args.buckets.split(",") if x.strip()) \
         or admission.bucket_classes()
@@ -515,7 +522,9 @@ def main() -> None:
                                      grid_bits=cfg.grid_bits,
                                      batch_bits=cfg.batch_bits)
     from repro.runtime import persist as persistlib
-    engine = ServeEngine(params, cfg, impl=args.impl, queue=queue,
+    engine = ServeEngine(params, cfg,
+                         impl=None if args.impl == "auto" else args.impl,
+                         queue=queue,
                          max_batch=args.max_batch,
                          persist_dir=args.persist_dir
                          or persistlib.default_dir())

@@ -124,6 +124,8 @@ def main() -> None:
                     help="private PinnedStore byte budget (default: the "
                          "process-wide store)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     run_stream(CONFIGS[args.config], args.frames, args.voxels,
                max_blocks=args.max_blocks, window=args.window,
                step=args.step, depth=args.depth, density=args.density,

@@ -100,7 +100,7 @@ def make_spconv_step(cfg, opt_cfg, plans, *, impl: str | None = None):
 
 
 def run_spconv_demo(steps: int = 2, *, voxels: int = 128, cfg=None,
-                    impl: str | None = "ref", seed: int = 0, cache=None,
+                    impl: str | None = None, seed: int = 0, cache=None,
                     scene: str = "indoor", replay: bool = True,
                     faults=None, ckpt_dir: str | None = None,
                     max_blocks: int | None = None, validate=None,
@@ -119,10 +119,10 @@ def run_spconv_demo(steps: int = 2, *, voxels: int = 128, cfg=None,
     cached plan objects are identical (`MinkPlans` identity keys the
     jitted-fn memo).
 
-    ``impl`` defaults to the pure-jnp ``'ref'`` backend so the CI gates
-    are deterministic on CPU hosts; pass ``impl=None`` to resolve the
-    real backend per host (``REPRO_KERNEL_IMPL`` / the fused Pallas
-    kernel on TPU — the CLI's ``--impl auto`` does exactly that).
+    ``impl`` None resolves the backend per host (``REPRO_KERNEL_IMPL`` /
+    the fused Pallas kernel on TPU, the pure-jnp ``'ref'`` elsewhere —
+    the CLI's ``--impl auto``); callers that need the CPU oracle on any
+    host pass ``impl='ref'``.
 
     This loop is also the end-to-end face of the hardened runtime
     (DESIGN.md §11): every cloud passes through the ingress sanitizer
@@ -289,6 +289,8 @@ def main() -> None:
                     help="lr-schedule horizon when resuming a partial run "
                          "(default: --steps)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.arch == "minkunet":
         from repro.runtime import persist as persistlib

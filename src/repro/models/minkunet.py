@@ -232,7 +232,11 @@ def forward(params, st: SparseTensor, cfg: MinkUNetConfig, *,
                                   plan=plans.subm[n_enc - 1 - i]
                                   if plans else None, act=act)
 
-    logits = st.feats @ params["head"]["w"][0] + params["head"]["b"]
+    # f32 end to end: at default precision a TPU would round this dot's
+    # operands to bf16 (the sparse convs run at fp32 in-kernel, §6)
+    logits = jnp.dot(st.feats, params["head"]["w"][0],
+                     precision=jax.lax.Precision.HIGHEST)
+    logits = logits + params["head"]["b"]
     return jnp.where(st.valid[:, None], logits, 0)
 
 

@@ -118,8 +118,8 @@ def moe_ffn(params, x, cfg):
             and "model" not in rs.batch_axes()):
         from jax.sharding import PartitionSpec as P
 
-        from repro.runtime.sharding_compat import get_abstract_mesh
-        from repro.runtime.sharding_compat import shard_map as _shard_map
+        from jax import shard_map as _shard_map
+        from jax.sharding import get_abstract_mesh
 
         mesh = get_abstract_mesh()
         bspec = rs.resolve("batch", shape=(b,))[0]

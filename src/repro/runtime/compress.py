@@ -50,7 +50,7 @@ def grad_allreduce_compressed(grads, mesh, axis: str = "pod"):
 
     spec = jax.tree.map(lambda _: P(), grads,
                         is_leaf=lambda x: hasattr(x, "shape"))
-    from repro.runtime.sharding_compat import shard_map
+    from jax import shard_map
     fn = shard_map(per_shard, mesh=mesh, in_specs=(spec,),
                    out_specs=spec, check_vma=False)
     return fn(grads)

@@ -10,17 +10,21 @@ the others bind at construction or import as noted):
     :func:`repro.kernels.octent.ops.search_impl`: ``auto`` picks the
     mesh-partitioned engine when the active mesh shards the block-key
     axes, else the compiled Pallas kernel on TPU / its XLA bit-oracle
-    ``ref`` elsewhere. ``interpret`` runs the same kernel under the
-    Pallas interpreter (CI hosts); ``xla`` is the retained dense-table
-    builder (the PR-1-style oracle).
+    ``ref`` on any other backend. ``interpret`` runs the same kernel
+    under the Pallas interpreter (CPU tests only; nothing on the serve
+    path chooses it); ``xla`` is the retained dense-table builder.
 
 ``REPRO_KERNEL_IMPL``
     Rulebook-execution backend — ``auto`` (default) | ``pallas`` |
     ``interpret`` | ``ref``. Resolved by
     :func:`repro.kernels.spconv_gemm.ops.kernel_impl`: ``auto`` is the
     compiled fused kernel on TPU, the pure-jnp tile oracle ``ref``
-    elsewhere. (The pure-XLA tap scan is not an env choice; request it
-    per call with ``impl='xla'``.)
+    elsewhere. It is what every entry point uses by default:
+    ``spconv_serve --impl auto``, ``ServeEngine(impl=None)``,
+    ``train --impl auto`` and ``run_spconv_demo(impl=None)``. A kernel
+    that fails to lower raises; it is never served by ``ref`` in its
+    place (runtime/guard.py). (The pure-XLA tap scan is not an env
+    choice; request it per call with ``impl='xla'``.)
 
 ``REPRO_SPAC_BLOCK``
     Set to ``0`` to disable Cin-block-grain SPAC skipping inside live

@@ -7,13 +7,15 @@ The hardening layer of DESIGN.md §11, threaded through the whole stack:
     quarantines, replans, runner recoveries). Flat dotted counter names;
     ``health().snapshot()`` for a JSON-able copy, ``delta()`` for
     per-run accounting.
-  * :func:`dispatch` — impl dispatch with a fallback chain. The primary
-    impl is tried twice (a transient fault — an injected one-shot, a
-    flaky lowering — recovers on the retry *with the same impl*, which
-    is what keeps results bit-identical under the chaos gate); a
-    persistent failure quarantines the (site, impl, shape-class) for
+  * :func:`dispatch` — impl dispatch with a fallback chain for injected
+    faults. The primary impl is tried twice (an injected one-shot fault
+    recovers on the retry *with the same impl*, which is what keeps
+    results bit-identical under the chaos gate); a persistent one
+    quarantines the (site, impl, shape-class) for
     ``REPRO_GUARD_COOLDOWN`` calls and walks the fallback chain (the
-    bit-exact ``ref`` oracles of kernels/*/ref.py).
+    bit-exact ``ref`` oracles of kernels/*/ref.py). Any other exception —
+    a kernel that fails to lower or compile above all — propagates:
+    serving the oracle in its place would hide the device path.
   * :func:`with_replan` — overflow-adaptive replanning. Catches
     :class:`~repro.core.validate.CapacityOverflow` from an eager build
     *and* checks the post-jit ``ConvPlan.overflow`` flag of a built
@@ -33,6 +35,7 @@ import os
 import threading
 
 from repro.core import validate
+from repro.runtime.fault import InjectedFault
 
 log = logging.getLogger("repro.guard")
 
@@ -184,7 +187,8 @@ def _quarantined(qkey) -> bool:
 
 
 def dispatch(site: str, impl: str, fallbacks, call, *, key=()):
-    """Run ``call(impl)`` with retry-then-fallback semantics.
+    """Run ``call(impl)`` with retry-then-fallback semantics for
+    :class:`~repro.runtime.fault.InjectedFault`.
 
     Args:
       site: failure site name ('search' | 'gemm'), keyed into health
@@ -197,12 +201,13 @@ def dispatch(site: str, impl: str, fallbacks, call, *, key=()):
         lowering failure on one shape class does not bench the impl for
         others.
 
-    The primary is attempted twice before falling back: a transient
-    failure (injected one-shot fault, flaky compile) recovers with the
-    *same* impl, keeping results bit-identical. A persistent failure
-    quarantines the primary for :func:`fallback_cooldown` subsequent
-    calls and serves the first working fallback. With the chain
-    disabled (``REPRO_GUARD_FALLBACK=0``) the first error propagates.
+    The primary is attempted twice before falling back: a one-shot
+    injected fault recovers with the *same* impl, keeping results
+    bit-identical. A persistent one quarantines the primary for
+    :func:`fallback_cooldown` subsequent calls and serves the first
+    working fallback. Every other exception propagates at once, never
+    served by a fallback; with the chain disabled
+    (``REPRO_GUARD_FALLBACK=0``) injected faults propagate too.
     """
     if not fallback_enabled():
         return call(impl)
@@ -217,7 +222,7 @@ def dispatch(site: str, impl: str, fallbacks, call, *, key=()):
                 if attempt:
                     _HEALTH.note(f"retry.ok.{site}")
                 return out
-            except Exception as e:              # noqa: BLE001
+            except InjectedFault as e:
                 err = e
                 _HEALTH.note(f"fallback.error.{site}")
                 log.warning("%s impl=%r failed (attempt %d): %s",
@@ -234,7 +239,7 @@ def dispatch(site: str, impl: str, fallbacks, call, *, key=()):
             _HEALTH.note(f"fallback.served.{site}")
             _HEALTH.note(f"fallback.served.{site}.{fb}")
             return out
-        except Exception as e:                  # noqa: BLE001
+        except InjectedFault as e:
             err = e
             _HEALTH.note(f"fallback.error.{site}")
             log.warning("%s fallback impl=%r failed too: %s", site, fb, e)

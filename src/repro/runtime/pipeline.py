@@ -13,13 +13,9 @@ devices, subprocess) against the unpipelined reference.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-from repro.runtime import sharding_compat
 
 
 def pipeline_apply(stage_params, x_mb, stage_fn, *, mesh, axis: str = "pod",
@@ -69,9 +65,8 @@ def pipeline_apply(stage_params, x_mb, stage_fn, *, mesh, axis: str = "pod",
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params,
                              is_leaf=lambda x: hasattr(x, "shape")),
                 extra_spec)
-    fn = sharding_compat.shard_map(per_stage, mesh=mesh,
-                       in_specs=in_specs, out_specs=extra_spec,
-                       check_vma=False)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                       out_specs=extra_spec, check_vma=False)
     return fn(stage_params, x_mb)
 
 

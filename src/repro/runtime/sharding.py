@@ -15,10 +15,9 @@ multi-pod mesh.
 from __future__ import annotations
 
 import jax
+from jax._src import mesh as _mesh_lib
 from jax.sharding import PartitionSpec as P
-
-from repro.runtime.sharding_compat import (concrete_device_ids,
-                                           get_abstract_mesh)
+from jax.sharding import get_abstract_mesh
 
 AXIS_POD = "pod"
 AXIS_DATA = "data"
@@ -140,7 +139,7 @@ def mesh_fingerprint(mesh=None) -> tuple:
     plan pinned to the wrong chips — so the fingerprint also carries the
     device ids backing the mesh (recovered from the context's concrete
     mesh when the active mesh is abstract; see
-    sharding_compat.concrete_device_ids).
+    :func:`concrete_device_ids`).
     """
     if mesh is None:
         mesh = get_abstract_mesh()
@@ -151,3 +150,27 @@ def mesh_fingerprint(mesh=None) -> tuple:
     if ids:
         fp += (ids,)
     return fp
+
+
+def concrete_mesh(mesh=None):
+    """The physical :class:`jax.sharding.Mesh` behind ``mesh`` (or the
+    active mesh); None off-mesh.
+
+    An AbstractMesh (what ``jax.sharding.get_abstract_mesh`` returns under
+    ``jax.set_mesh``) carries no devices, so the concrete mesh that
+    ``jax.set_mesh`` installed for this context stands in — read through
+    jax's mesh module, because the public ``jax.sharding.get_mesh``
+    refuses to run inside ``jax.jit``.
+    """
+    if not isinstance(mesh, jax.sharding.Mesh):
+        mesh = _mesh_lib.get_concrete_mesh()
+    return None if mesh is None or mesh.empty else mesh
+
+
+def concrete_device_ids(mesh=None) -> tuple:
+    """Device ids backing ``mesh`` (or the active mesh); () off-mesh.
+    Without them, two same-shape meshes over different device subsets
+    would be indistinguishable to callers keying caches on the mesh."""
+    mesh = concrete_mesh(mesh)
+    return () if mesh is None else tuple(
+        int(i) for i in mesh.device_ids.ravel())

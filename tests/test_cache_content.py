@@ -217,7 +217,7 @@ def test_mesh_change_invalidates_identical_content():
     """Same bytes under a different mesh must rebuild (§9 fingerprint in
     every key) — and return to the off-mesh entry afterwards."""
     from jax.sharding import Mesh
-    from repro.runtime.sharding_compat import set_mesh
+    from jax import set_mesh
 
     rng = np.random.default_rng(4)
     coords, bidx, valid = _cloud(rng)

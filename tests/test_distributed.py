@@ -10,7 +10,8 @@ from tests.proptest import run_script
 def test_pipeline_matches_sequential():
     out = run_script("""
 import numpy as np, jax, jax.numpy as jnp
-from repro.runtime.sharding_compat import AxisType, make_mesh, set_mesh
+from jax import make_mesh, set_mesh
+from jax.sharding import AxisType
 from repro.runtime.pipeline import pipeline_apply, stack_stages
 
 mesh = make_mesh((4, 2), ("pod", "data"),
@@ -46,8 +47,8 @@ def test_compressed_psum_close_to_exact():
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.runtime.compress import compressed_psum_mean
-from repro.runtime.sharding_compat import (AxisType, make_mesh, set_mesh,
-                                           shard_map)
+from jax import make_mesh, set_mesh, shard_map
+from jax.sharding import AxisType
 
 mesh = make_mesh((8,), ("pod",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(1)
@@ -76,7 +77,7 @@ def test_sharded_train_step_matches_single_device():
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.launch import shardings
-from repro.runtime.sharding_compat import set_mesh
+from jax import set_mesh
 from repro.launch.mesh import make_test_mesh
 from repro.launch.train import make_train_step, init_state
 from repro.models import api
@@ -122,7 +123,7 @@ def test_dryrun_cell_on_test_mesh():
 import numpy as np, jax
 from repro.configs import get_config, SHAPE_CELLS
 from repro.launch.mesh import make_test_mesh
-from repro.runtime.sharding_compat import set_mesh
+from jax import set_mesh
 from repro.launch import shardings
 from repro.launch.dryrun import build_cell
 from repro.models import api

@@ -224,6 +224,24 @@ def test_search_fallback_is_bit_identical():
     assert guard.health().get("fallback.served.search.ref") == 1
 
 
+def test_dispatch_reraises_real_errors_without_serving_ref():
+    """Only an InjectedFault walks the chain: a kernel that fails to
+    lower or compile raises at once, and the oracle is never called."""
+    calls = []
+
+    def call(impl):
+        calls.append(impl)
+        if impl == "pallas":
+            raise NotImplementedError("Only 2D gather is supported")
+        return impl
+
+    with pytest.raises(NotImplementedError, match="2D gather"):
+        guard.dispatch("search", "pallas", ("ref",), call, key=("k",))
+    assert calls == ["pallas"]
+    h = guard.health().snapshot()
+    assert not any(k.startswith(("fallback.", "quarantine.")) for k in h), h
+
+
 def test_fallback_disabled_propagates(monkeypatch):
     monkeypatch.setenv("REPRO_GUARD_FALLBACK", "0")
     _, plan, feats, w = _small_plan_and_operands()

@@ -168,7 +168,7 @@ def test_plan_cache_misses_when_mesh_shape_changes():
     arrays under a different mesh shape rebuild (a plan embeds that
     mesh's sharded search), and the same mesh hits again."""
     from jax.sharding import Mesh
-    from repro.runtime.sharding_compat import set_mesh
+    from jax import set_mesh
 
     rng = np.random.default_rng(21)
     st = _rand_st(rng, 24, 10, 1, 4)
@@ -202,7 +202,7 @@ def test_minkunet_search_count_flat_under_mesh():
     from jax.sharding import Mesh
     from repro.data import pointcloud
     from repro.models import minkunet
-    from repro.runtime.sharding_compat import set_mesh
+    from jax import set_mesh
 
     cfg = minkunet.MinkUNetConfig(stem=8, enc=(8, 16), dec=(16, 8),
                                   classes=4, blocks=2)
@@ -466,7 +466,7 @@ def test_sharded_plan_grads_match_single_device():
     sharded OCTENT search must backprop exactly like the single-device
     plan (multi-device variant: tests/test_sharded_search.py)."""
     from jax.sharding import Mesh
-    from repro.runtime.sharding_compat import set_mesh
+    from jax import set_mesh
 
     rng = np.random.default_rng(23)
     n, cin, cout = 32, 8, 12

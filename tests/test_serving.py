@@ -301,7 +301,7 @@ def test_dispatch_quarantine_cooldown_expiry_readmits(monkeypatch):
         if impl == "fast":
             state["primary_calls"] += 1
             if state["fail_primary"]:
-                raise RuntimeError("lowering broke")
+                raise fault.InjectedFault("gemm", state["primary_calls"])
         return impl
 
     run = lambda: guard.dispatch("gemm", "fast", ("ref",), call, key=("k",))
@@ -344,6 +344,8 @@ def test_dump_health_json(tmp_path):
 def test_train_cli_writes_health_json(tmp_path, monkeypatch):
     from repro.launch import train
     path = tmp_path / "train_health.json"
+    # main() places JAX's compile cache; keep this worker's where it was
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax"))
     monkeypatch.setattr("sys.argv",
                         ["train", "--arch", "minkunet", "--steps", "1",
                          "--voxels", "64", "--impl", "ref",

@@ -15,11 +15,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import set_mesh
 from jax.sharding import Mesh
 
 from repro.kernels.octent import ops as oct_ops
 from repro.runtime import sharding
-from repro.runtime.sharding_compat import set_mesh
 from tests.proptest import forall, random_cloud, run_script
 
 
@@ -93,7 +93,7 @@ def test_sharded_parity_multiway():
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.kernels.octent import ops as oct_ops
-from repro.runtime.sharding_compat import set_mesh
+from jax import set_mesh
 from tests.proptest import random_cloud
 
 n = 120            # fixed size so each mesh's lowering caches across cases
@@ -140,7 +140,7 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 from repro.core import binning, morton, plan as planlib
 from repro.kernels.octent import ops as oct_ops, sharded
-from repro.runtime.sharding_compat import set_mesh
+from jax import set_mesh
 from tests.proptest import random_cloud
 
 rng = np.random.default_rng(0)
@@ -211,7 +211,7 @@ from repro.core import plan as planlib, spconv
 from repro.core.spconv import SparseTensor
 from repro.data import pointcloud
 from repro.models import minkunet
-from repro.runtime.sharding_compat import set_mesh
+from jax import set_mesh
 from tests.proptest import random_cloud
 
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
