@@ -337,6 +337,7 @@ def _gather_rep(rep: jnp.ndarray, src: jnp.ndarray, fill=0):
 
 
 @partial(jax.jit, static_argnames=("grid_bits", "batch_bits"))
+@jax.named_scope("plan.search")
 def build_maps_gconv2(coords: jnp.ndarray, batch: jnp.ndarray,
                       valid: jnp.ndarray, *, grid_bits: int = 7,
                       batch_bits: int = 4) -> StridedMaps:
@@ -410,6 +411,8 @@ def build_maps_gconv3(coords: jnp.ndarray, batch: jnp.ndarray,
         n_true=n_true, overflow=n_true > budget)
 
 
+@jax.jit
+@jax.named_scope("plan.search")
 def transpose_maps(maps: StridedMaps, target_coords: jnp.ndarray,
                    target_batch: jnp.ndarray,
                    target_valid: jnp.ndarray) -> StridedMaps:
@@ -423,6 +426,7 @@ def transpose_maps(maps: StridedMaps, target_coords: jnp.ndarray,
 
 
 @partial(jax.jit, static_argnames=("n_out", "n_taps"))
+@jax.named_scope("plan.search")
 def strided_to_kmap(maps: StridedMaps, *, n_out: int, n_taps: int) -> jnp.ndarray:
     """Convert scatter triples to gather-form kmap (n_out, n_taps).
 

@@ -74,11 +74,12 @@ from typing import NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import mapsearch, morton, rulebook, sparsity, validate
 from repro.core.mapsearch import StridedMaps
 from repro.kernels.spconv_gemm import ops as sg_ops
-from repro.runtime import fault, feature_cache, sharding
+from repro.runtime import fault, feature_cache, guard, sharding
 
 
 def _octent_ops():
@@ -132,6 +133,7 @@ def _mix32(x: jnp.ndarray) -> jnp.ndarray:
 
 
 @jax.jit
+@jax.named_scope("plan.fingerprint")
 def _fp_words(flat: jnp.ndarray) -> jnp.ndarray:
     """(3,) uint32 fingerprint words of a flat int32 array.
 
@@ -177,6 +179,7 @@ def array_fingerprint(a) -> tuple | None:
         else:
             flat = jnp.ravel(a).astype(jnp.int32)
         words = np.asarray(_fp_words(flat))
+        guard.health().note("plan.host_sync")
     # chaos hook: the 'fingerprint' fault site corrupts the words to
     # model a content-key collision (runtime/fault.py); a verifying
     # cache detects the mismatch and rebuilds instead of serving stale
@@ -405,7 +408,10 @@ class PlanCache:
             self.id_hits += 1
             return self._entries[canonical].plan
 
-        fp = content_fingerprint(arrays) if self.content else None
+        fp = None
+        if self.content:
+            with TraceAnnotation("plan.fingerprint"):
+                fp = content_fingerprint(arrays)
         if fp is not None:
             ckey = (fp, statics)
             entry = self._entries.get(ckey)
@@ -467,9 +473,11 @@ def _require_block_capacity(n_blocks, max_blocks: int):
     """
     overflow = jnp.asarray(n_blocks, jnp.int32) > max_blocks
     try:
-        concrete = bool(overflow)
+        with TraceAnnotation("plan.check"):
+            concrete = bool(overflow)
     except jax.errors.ConcretizationTypeError:
         return overflow
+    guard.health().note("plan.host_sync")
     if concrete:
         raise validate.CapacityOverflow(
             "block_table",
@@ -489,12 +497,15 @@ def _require_out_capacity(overflow_flag, n_true, budget: int):
     () bool flag is returned and carried on ``ConvPlan.overflow``."""
     overflow = jnp.asarray(overflow_flag, bool)
     try:
-        concrete = bool(overflow)
+        with TraceAnnotation("plan.check"):
+            concrete = bool(overflow)
     except jax.errors.ConcretizationTypeError:
         return overflow
+    guard.health().note("plan.host_sync")
     if concrete:
         try:
             needed = int(n_true)
+            guard.health().note("plan.host_sync")
         except (TypeError, jax.errors.ConcretizationTypeError):
             needed = None
         raise validate.CapacityOverflow(
@@ -599,26 +610,27 @@ def subm3_plan(coords, batch, valid, *, max_blocks: int,
                 # any dirty-row queries it runs are counted by
                 # octent.ops.QUERY_ROWS, not as a full map search
                 DELTA_PATCHES[0] += 1
-                kmap, table = warm.patch()
+                with TraceAnnotation("plan.search"):
+                    kmap, table = warm.patch()
                 overflow = _require_block_capacity(table.n_blocks,
                                                    max_blocks)
                 if pin_key is not None:
                     store.put(pin_key, table, anchor=anchor)
-                tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
-                return ConvPlan("subm3", kmap, tiles, coords.shape[0], 27,
-                                None, None, None, None, overflow)
-            MAPSEARCH_CALLS[0] += 1
-            if simpl in ("pallas", "interpret", "ref") and table is None:
-                table = oct_ops.build_query_table(
-                    coords, batch, valid, max_blocks=max_blocks,
-                    grid_bits=grid_bits, batch_bits=batch_bits)
-                if pin_key is not None:
-                    store.put(pin_key, table, anchor=anchor)
-            kmap, n_blocks = oct_ops.build_kmap(
-                coords, batch, valid, max_blocks=max_blocks,
-                grid_bits=grid_bits, batch_bits=batch_bits, impl=simpl,
-                offsets=offs, table=table)
-            overflow = _require_block_capacity(n_blocks, max_blocks)
+            else:
+                MAPSEARCH_CALLS[0] += 1
+                with TraceAnnotation("plan.search"):
+                    if simpl in ("pallas", "interpret", "ref") \
+                            and table is None:
+                        table = oct_ops.build_query_table(
+                            coords, batch, valid, max_blocks=max_blocks,
+                            grid_bits=grid_bits, batch_bits=batch_bits)
+                        if pin_key is not None:
+                            store.put(pin_key, table, anchor=anchor)
+                    kmap, n_blocks = oct_ops.build_kmap(
+                        coords, batch, valid, max_blocks=max_blocks,
+                        grid_bits=grid_bits, batch_bits=batch_bits,
+                        impl=simpl, offsets=offs, table=table)
+                overflow = _require_block_capacity(n_blocks, max_blocks)
         elif method == "sorted":
             MAPSEARCH_CALLS[0] += 1
             if not mapsearch.sorted_key_fits(grid_bits, batch_bits):
@@ -631,12 +643,14 @@ def subm3_plan(coords, batch, valid, *, max_blocks: int,
                     f"bits. Pass grid_bits <= "
                     f"{(31 - batch_bits - morton.LOCAL_CODE_BITS) // 3} or "
                     f"use method='octree' for large grids.")
-            kmap = mapsearch.build_kmap_sorted(
-                coords, batch, valid, offs,
-                grid_bits=grid_bits, batch_bits=batch_bits)
+            with TraceAnnotation("plan.search"):
+                kmap = mapsearch.build_kmap_sorted(
+                    coords, batch, valid, offs,
+                    grid_bits=grid_bits, batch_bits=batch_bits)
         else:
             raise ValueError(f"unknown map search method {method!r}")
-        tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
+        with TraceAnnotation("plan.tiles"):
+            tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
         return ConvPlan("subm3", kmap, tiles, coords.shape[0], 27,
                         None, None, None, None, overflow)
 
@@ -656,12 +670,14 @@ def gconv2_plan(coords, batch, valid, *, grid_bits: int = 7,
     def build(fp):
         fault.check("plan")
         MAPSEARCH_CALLS[0] += 1
-        maps = mapsearch.build_maps_gconv2(coords, batch, valid,
-                                           grid_bits=grid_bits,
-                                           batch_bits=batch_bits)
         n = coords.shape[0]
-        kmap = mapsearch.strided_to_kmap(maps, n_out=n, n_taps=8)
-        tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
+        with TraceAnnotation("plan.search"):
+            maps = mapsearch.build_maps_gconv2(coords, batch, valid,
+                                               grid_bits=grid_bits,
+                                               batch_bits=batch_bits)
+            kmap = mapsearch.strided_to_kmap(maps, n_out=n, n_taps=8)
+        with TraceAnnotation("plan.tiles"):
+            tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
         return ConvPlan("gconv2", kmap, tiles, n, 8,
                         maps.out_coords, maps.out_batch, maps.out_valid, maps)
 
@@ -709,11 +725,13 @@ def tconv2_plan(gconv2_maps: StridedMaps, target_coords, target_batch,
     statics = ("tconv2", bm, bo)
 
     def build(fp):
-        maps = mapsearch.transpose_maps(gconv2_maps, target_coords,
-                                        target_batch, target_valid)
         n = target_valid.shape[0]
-        kmap = mapsearch.strided_to_kmap(maps, n_out=n, n_taps=8)
-        tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
+        with TraceAnnotation("plan.search"):
+            maps = mapsearch.transpose_maps(gconv2_maps, target_coords,
+                                            target_batch, target_valid)
+            kmap = mapsearch.strided_to_kmap(maps, n_out=n, n_taps=8)
+        with TraceAnnotation("plan.tiles"):
+            tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
         return ConvPlan("tconv2", kmap, tiles, n, 8,
                         target_coords, target_batch, target_valid, maps)
 

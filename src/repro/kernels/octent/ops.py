@@ -113,6 +113,7 @@ class QueryTable(NamedTuple):
 
 @functools.partial(jax.jit, static_argnames=("max_blocks", "grid_bits",
                                              "batch_bits", "binning_mode"))
+@jax.named_scope("plan.search")
 def build_query_table(coords: jnp.ndarray, batch: jnp.ndarray,
                       valid: jnp.ndarray, *, max_blocks: int,
                       grid_bits: int = 7, batch_bits: int = 4,
@@ -169,6 +170,7 @@ def build_query_table(coords: jnp.ndarray, batch: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("bq",))
+@jax.named_scope("plan.search")
 def _pack_queries(coords, batch, valid, *, bq: int) -> jnp.ndarray:
     """Pack the voxel stream as (5, N_pad) int32 rows x/y/z/batch/valid."""
     n = coords.shape[0]

@@ -164,6 +164,7 @@ def build_tap_tiles(kmap: jnp.ndarray, row_nz: jnp.ndarray | None = None,
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bo", "schedule", "binning"))
+@jax.named_scope("plan.tiles")
 def _build_tap_tiles(kmap, row_nz, *, bm, bo, schedule, binning):
     n_out, k = kmap.shape
     n_blocks = -(-n_out // bo)

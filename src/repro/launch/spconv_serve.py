@@ -48,6 +48,7 @@ import time
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import plan as planlib
 from repro.core.spconv import SparseTensor
@@ -277,9 +278,11 @@ class ServeEngine:
         return run
 
     def _forward_fn(self, params, st: SparseTensor, plans):
-        dyn, treedef, static, skeleton = split_plans(plans)
-        fn = self._executable(skeleton, treedef, static, self._impl_now())
-        return fn(params, st.coords, st.batch, st.valid, st.feats, dyn)
+        with TraceAnnotation("serve.dispatch"):
+            dyn, treedef, static, skeleton = split_plans(plans)
+            fn = self._executable(skeleton, treedef, static,
+                                  self._impl_now())
+            return fn(params, st.coords, st.batch, st.valid, st.feats, dyn)
 
     # -- the continuous-batching tick ----------------------------------------
 
@@ -309,53 +312,56 @@ class ServeEngine:
 
     def _step(self) -> list[ServeResult]:
         self.ticks += 1
-        h0 = guard.health().snapshot()
-        tick_results: list[ServeResult] = []
+        with TraceAnnotation("serve.tick", tick=self.ticks) as span:
+            h0 = guard.health().snapshot()
+            tick_results: list[ServeResult] = []
 
-        if self.level >= LADDER_MAX:
-            for rej in self.queue.shed_all():
+            if self.level >= LADDER_MAX:
+                for rej in self.queue.shed_all():
+                    self._record_rejection(rej)
+                    tick_results.append(self.results[-1])
+                self._ladder_update(h0, had_failures=False)
+                return tick_results
+
+            with TraceAnnotation("serve.admit"):
+                reqs, shed = self.queue.take(
+                    self._effective_batch(), est_service_s=self._est_service)
+            span.set_metadata(batch=len(reqs))
+            for rej in shed:
                 self._record_rejection(rej)
                 tick_results.append(self.results[-1])
-            self._ladder_update(h0, had_failures=False)
-            return tick_results
+            if not reqs:
+                self._ladder_update(h0, had_failures=False)
+                return tick_results
 
-        reqs, shed = self.queue.take(self._effective_batch(),
-                                     est_service_s=self._est_service)
-        for rej in shed:
-            self._record_rejection(rej)
-            tick_results.append(self.results[-1])
-        if not reqs:
-            self._ladder_update(h0, had_failures=False)
-            return tick_results
+            # the 'batch' fault site attacks batch assembly itself; one-shot
+            # faults recover on the retry, persistent ones isolate only this
+            # tick's requests
+            batch_dead = None
+            for attempt in (0, 1):
+                try:
+                    fault.check("batch")
+                    break
+                except fault.InjectedFault as e:
+                    if attempt:
+                        batch_dead = e
+                    else:
+                        guard.health().note("serve.batch_retry")
+            if batch_dead is not None:
+                for req in reqs:
+                    guard.health().note("serve.isolated")
+                    res = ServeResult(req.rid, "isolated",
+                                      reason=admission.ISOLATED_FAULT,
+                                      bucket=req.bucket)
+                    self.results.append(res)
+                    tick_results.append(res)
+                self._ladder_update(h0, had_failures=True)
+                return tick_results
 
-        # the 'batch' fault site attacks batch assembly itself; one-shot
-        # faults recover on the retry, persistent ones isolate only this
-        # tick's requests
-        batch_dead = None
-        for attempt in (0, 1):
-            try:
-                fault.check("batch")
-                break
-            except fault.InjectedFault as e:
-                if attempt:
-                    batch_dead = e
-                else:
-                    guard.health().note("serve.batch_retry")
-        if batch_dead is not None:
-            for req in reqs:
-                guard.health().note("serve.isolated")
-                res = ServeResult(req.rid, "isolated",
-                                  reason=admission.ISOLATED_FAULT,
-                                  bucket=req.bucket)
-                self.results.append(res)
-                tick_results.append(res)
-            self._ladder_update(h0, had_failures=True)
+            tick_results.extend(self._execute_batch(reqs))
+            failed = any(r.status == "isolated" for r in tick_results)
+            self._ladder_update(h0, had_failures=failed)
             return tick_results
-
-        tick_results.extend(self._execute_batch(reqs))
-        failed = any(r.status == "isolated" for r in tick_results)
-        self._ladder_update(h0, had_failures=failed)
-        return tick_results
 
     def _execute_batch(self, reqs) -> list[ServeResult]:
         degraded = self.level > 0
@@ -364,13 +370,15 @@ class ServeEngine:
         results: list[ServeResult | None] = [None] * len(reqs)
 
         def build_one(req):
-            c = jnp.asarray(req.coords)
-            b = jnp.asarray(req.batch)
-            v = jnp.asarray(req.valid)
-            f = jnp.asarray(req.feats)
-            plans = minkunet.build_plans(c, b, v, self.model_cfg,
-                                         cache=self.cache, n_max=req.bucket)
-            return SparseTensor(c, b, v, f), plans
+            with TraceAnnotation("serve.build", rid=req.rid):
+                c = jnp.asarray(req.coords)
+                b = jnp.asarray(req.batch)
+                v = jnp.asarray(req.valid)
+                f = jnp.asarray(req.feats)
+                plans = minkunet.build_plans(c, b, v, self.model_cfg,
+                                             cache=self.cache,
+                                             n_max=req.bucket)
+                return SparseTensor(c, b, v, f), plans
 
         for i, req in enumerate(reqs):
             try:
@@ -404,18 +412,20 @@ class ServeEngine:
         for j, i in enumerate(live):
             if results[i] is not None:
                 continue
-            logits = np.asarray(outs[j])
-            done = self.clock()
             req = reqs[i]
-            self._note_service(req.bucket, done - req.submitted_at)
-            guard.health().note("serve.completed")
-            if degraded:
-                guard.health().note("serve.degraded")
-            results[i] = ServeResult(
-                req.rid, "completed", bucket=req.bucket,
-                latency_s=done - req.submitted_at, degraded=degraded,
-                digest=hashlib.sha256(logits.tobytes()).hexdigest(),
-                logits=logits)
+            with TraceAnnotation("serve.fetch", rid=req.rid):
+                logits = np.asarray(outs[j])
+            with TraceAnnotation("serve.finish", rid=req.rid):
+                done = self.clock()
+                self._note_service(req.bucket, done - req.submitted_at)
+                guard.health().note("serve.completed")
+                if degraded:
+                    guard.health().note("serve.degraded")
+                results[i] = ServeResult(
+                    req.rid, "completed", bucket=req.bucket,
+                    latency_s=done - req.submitted_at, degraded=degraded,
+                    digest=hashlib.sha256(logits.tobytes()).hexdigest(),
+                    logits=logits)
         final = [r for r in results if r is not None]
         self.results.extend(final)
         return final

@@ -6,11 +6,13 @@ Tconv2 upsampling with exact coordinate recovery (§IV-D2) + skip concat.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import plan as planlib
 from repro.core import spconv
@@ -80,18 +82,26 @@ def _apply_subm(st, params, cfg, training, n_max, cache, impl, plan=None,
     it to the next block at the same resolution so its SPAC liveness
     refresh costs no HBM sweep (DESIGN.md §14)."""
     if cfg.fused_epilogue and not training:
-        return spconv.subm_conv3_bn_relu(
-            st, params["conv"], params["bn"], max_blocks=n_max,
-            method=cfg.map_method, grid_bits=cfg.grid_bits,
-            batch_bits=cfg.batch_bits, spac=cfg.spac, act=act, plan=plan,
-            cache=cache, impl=impl, bm=cfg.bm, bo=cfg.bo)
-    st = spconv.subm_conv3(st, params["conv"], max_blocks=n_max,
-                           method=cfg.map_method, grid_bits=cfg.grid_bits,
-                           batch_bits=cfg.batch_bits, spac=cfg.spac,
-                           act=act, plan=plan, cache=cache, impl=impl,
-                           bm=cfg.bm, bo=cfg.bo)
-    st, _ = spconv.batch_norm(st, params["bn"], training=training)
-    return spconv.relu(st), None
+        with jax.named_scope("fwd.subm3"):
+            return spconv.subm_conv3_bn_relu(
+                st, params["conv"], params["bn"], max_blocks=n_max,
+                method=cfg.map_method, grid_bits=cfg.grid_bits,
+                batch_bits=cfg.batch_bits, spac=cfg.spac, act=act,
+                plan=plan, cache=cache, impl=impl, bm=cfg.bm, bo=cfg.bo)
+    with jax.named_scope("fwd.subm3"):
+        st = spconv.subm_conv3(st, params["conv"], max_blocks=n_max,
+                               method=cfg.map_method,
+                               grid_bits=cfg.grid_bits,
+                               batch_bits=cfg.batch_bits, spac=cfg.spac,
+                               act=act, plan=plan, cache=cache, impl=impl,
+                               bm=cfg.bm, bo=cfg.bo)
+    return _bn_relu(st, params["bn"], training), None
+
+
+def _bn_relu(st, bn, training):
+    with jax.named_scope("fwd.bn_relu"):
+        st, _ = spconv.batch_norm(st, bn, training=training)
+        return spconv.relu(st)
 
 
 class MinkPlans(NamedTuple):
@@ -110,6 +120,7 @@ class MinkPlans(NamedTuple):
     up: tuple     # per decoder stage: the Tconv2 plan
 
 
+@functools.partial(jax.profiler.annotate_function, name="plan.build")
 def build_plans(coords, batch, valid, cfg: MinkUNetConfig, *,
                 cache: planlib.PlanCache | None = None,
                 n_max: int | None = None,
@@ -141,31 +152,36 @@ def build_plans(coords, batch, valid, cfg: MinkUNetConfig, *,
     n_max = coords.shape[0] if n_max is None else n_max
     gb, bb = cfg.grid_bits, cfg.batch_bits
 
-    def subm(c, b, v):
+    def subm(r, c, b, v):
         def build(mb):
             return planlib.subm3_plan(c, b, v, max_blocks=mb,
                                       method=cfg.map_method, grid_bits=gb,
                                       batch_bits=bb, bm=cfg.bm, bo=cfg.bo,
                                       cache=cache)
-        if not replan:
-            return build(n_max)
-        return guard.with_replan(build, n_max,
-                                 key=("minkunet-subm3", c.shape[0], gb, bb))
+        with TraceAnnotation("plan.subm3", r=r):
+            if not replan:
+                return build(n_max)
+            return guard.with_replan(build, n_max,
+                                     key=("minkunet-subm3", c.shape[0], gb,
+                                          bb))
 
+    # ``r`` on each stage's span: the resolution its outputs live at
     cur = (coords, batch, valid)
-    subms, downs, stack = [subm(*cur)], [], [cur]
-    for _ in range(len(cfg.enc)):
-        d = planlib.gconv2_plan(*cur, grid_bits=gb, batch_bits=bb,
-                                bm=cfg.bm, bo=cfg.bo, cache=cache)
+    subms, downs, stack = [subm(0, *cur)], [], [cur]
+    for i in range(len(cfg.enc)):
+        with TraceAnnotation("plan.gconv2", r=i + 1):
+            d = planlib.gconv2_plan(*cur, grid_bits=gb, batch_bits=bb,
+                                    bm=cfg.bm, bo=cfg.bo, cache=cache)
         cur = (d.out_coords, d.out_batch, d.out_valid)
         downs.append(d)
-        subms.append(subm(*cur))
+        subms.append(subm(i + 1, *cur))
         stack.append(cur)
     ups = []
     for i in range(len(cfg.dec)):
         target = stack[-(i + 2)]
-        ups.append(planlib.tconv2_plan(downs[-(i + 1)].maps, *target,
-                                       bm=cfg.bm, bo=cfg.bo, cache=cache))
+        with TraceAnnotation("plan.tconv2", r=len(cfg.enc) - 1 - i):
+            ups.append(planlib.tconv2_plan(downs[-(i + 1)].maps, *target,
+                                           bm=cfg.bm, bo=cfg.bo, cache=cache))
     return MinkPlans(tuple(subms), tuple(downs), tuple(ups))
 
 
@@ -190,7 +206,8 @@ def forward(params, st: SparseTensor, cfg: MinkUNetConfig, *,
         cache = planlib.PlanCache()
     n_max = st.n_max
     n_enc = len(cfg.enc)
-    st = spconv.mask_feats(st)
+    with jax.named_scope("fwd.subm3"):
+        st = spconv.mask_feats(st)
     st, _ = _apply_subm(st, params["stem"], cfg, training, n_max, cache,
                         impl, plan=plans.subm[0] if plans else None)
 
@@ -198,13 +215,13 @@ def forward(params, st: SparseTensor, cfg: MinkUNetConfig, *,
     gb = cfg.grid_bits
     for i in range(n_enc):
         stage = params[f"enc{i}"]
-        down, maps = spconv.gconv2(st, stage["down"]["conv"], grid_bits=gb,
-                                   batch_bits=cfg.batch_bits,
-                                   plan=plans.down[i] if plans else None,
-                                   cache=cache, impl=impl, bm=cfg.bm,
-                                   bo=cfg.bo)
-        down, _ = spconv.batch_norm(down, stage["down"]["bn"], training=training)
-        st = spconv.relu(down)
+        with jax.named_scope("fwd.down"):
+            down, maps = spconv.gconv2(
+                st, stage["down"]["conv"], grid_bits=gb,
+                batch_bits=cfg.batch_bits,
+                plan=plans.down[i] if plans else None, cache=cache,
+                impl=impl, bm=cfg.bm, bo=cfg.bo)
+        st = _bn_relu(down, stage["down"]["bn"], training)
         act = None    # new resolution/channels: previous masks don't apply
         for b in range(cfg.blocks):
             st, act = _apply_subm(st, stage[f"block{b}"], cfg, training,
@@ -218,13 +235,14 @@ def forward(params, st: SparseTensor, cfg: MinkUNetConfig, *,
         stage = params[f"dec{i}"]
         maps = maps_stack[-(i + 1)]
         target = skips[-(i + 2)]
-        up = spconv.tconv2(st, stage["up"]["conv"], maps, target,
-                           plan=plans.up[i] if plans else None,
-                           cache=cache, impl=impl, bm=cfg.bm, bo=cfg.bo)
-        up, _ = spconv.batch_norm(up, stage["up"]["bn"], training=training)
-        up = spconv.relu(up)
-        st = up.replace_feats(
-            jnp.concatenate([up.feats, target.feats], axis=-1))
+        with jax.named_scope("fwd.up"):
+            up = spconv.tconv2(st, stage["up"]["conv"], maps, target,
+                               plan=plans.up[i] if plans else None,
+                               cache=cache, impl=impl, bm=cfg.bm, bo=cfg.bo)
+        up = _bn_relu(up, stage["up"]["bn"], training)
+        with jax.named_scope("fwd.concat"):
+            st = up.replace_feats(
+                jnp.concatenate([up.feats, target.feats], axis=-1))
         act = None    # concat changed the channel layout: masks are stale
         for b in range(cfg.blocks):
             st, act = _apply_subm(st, stage[f"block{b}"], cfg, training,
@@ -234,10 +252,11 @@ def forward(params, st: SparseTensor, cfg: MinkUNetConfig, *,
 
     # f32 end to end: at default precision a TPU would round this dot's
     # operands to bf16 (the sparse convs run at fp32 in-kernel, §6)
-    logits = jnp.dot(st.feats, params["head"]["w"][0],
-                     precision=jax.lax.Precision.HIGHEST)
-    logits = logits + params["head"]["b"]
-    return jnp.where(st.valid[:, None], logits, 0)
+    with jax.named_scope("fwd.head"):
+        logits = jnp.dot(st.feats, params["head"]["w"][0],
+                         precision=jax.lax.Precision.HIGHEST)
+        logits = logits + params["head"]["b"]
+        return jnp.where(st.valid[:, None], logits, 0)
 
 
 def forward_multicloud(params, clouds, cfg: MinkUNetConfig, *,
