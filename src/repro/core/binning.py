@@ -7,9 +7,13 @@ overkill: a stable counting sort reproduces the exact same permutation
 from bincount + prefix-sum passes, with no XLA ``sort`` primitive anywhere
 in the jaxpr. That matters on TPU because ``sort`` lowers to a bitonic
 network over the full key stream (O(n log^2 n) compare-exchange cycles),
-while each counting pass is one one-hot cumsum + two scatters (O(n) HBM
-traffic), and it matters to this repo because the acceptance contract of
+while each counting pass is one one-hot cumsum and two permutation
+scatters, and it matters to this repo because the acceptance contract of
 the sort-free plan build is jaxpr-auditable (:func:`sort_op_count`).
+Those scatters touch O(n) elements but do not cost O(n) HBM time on a
+TPU: with colliding or unordered indices they run close to one element
+at a time. A pass that can place its elements by reading should
+(the tile build does, kernels/spconv_gemm/ops.py; DESIGN.md §5).
 
 Two entry points:
 

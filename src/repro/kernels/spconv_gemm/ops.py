@@ -146,14 +146,17 @@ def build_tap_tiles(kmap: jnp.ndarray, row_nz: jnp.ndarray | None = None,
     geometry-only tiles for a cached plan and refresh liveness per layer
     with :func:`tile_liveness` instead.
 
-    ``binning`` selects the layout's ordering pass (DESIGN.md §5): the
-    default ``'counting'`` derives every slot position in closed form
-    (group starts from a bincount, stable within-group ranks from a
-    segment-reset cumsum — exactly one map per (output row, tap) makes the
-    stable counting rank computable without reordering anything), so the
-    build contains zero XLA ``sort`` ops. ``'argsort'`` is the retained
-    27N-key global-argsort baseline; both produce bit-identical tiles
-    (tested).
+    ``binning`` selects how slots are placed (DESIGN.md §5). The default
+    ``'counting'`` places every slot by reading, with no XLA ``sort``, no
+    scatter and no per-map gather over the n_out*K map stream: group
+    counts are dense reductions over a (block, tap, row) view of the kmap,
+    a map's stable within-group rank is a cumsum down its column (exactly
+    one map per (output row, tap) makes that the counting rank), and each
+    single-(block, tap) tile fetches its group's bo-long column once and
+    picks the map of each of its bm slots by compare-and-select.
+    ``'argsort'`` is the retained baseline (bincounts, a 27N-key global
+    argsort and three permutation scatters); both produce bit-identical
+    tiles (tested).
     """
     if bo is None:
         bo = max(bm, 512)
@@ -167,94 +170,20 @@ def build_tap_tiles(kmap: jnp.ndarray, row_nz: jnp.ndarray | None = None,
 @jax.named_scope("plan.tiles")
 def _build_tap_tiles(kmap, row_nz, *, bm, bo, schedule, binning):
     n_out, k = kmap.shape
-    n_blocks = -(-n_out // bo)
-    g_total = n_blocks * k
     m_pad = _padded_budget(n_out, k, bm, bo)
     grp = GRP if bm % GRP == 0 else bm
     n_grp = bm // grp
     assert n_grp <= 32, (bm, grp)
-
-    flat_in = kmap.reshape(-1)
-    taps = jnp.tile(jnp.arange(k, dtype=jnp.int32), n_out)
-    outs = jnp.repeat(jnp.arange(n_out, dtype=jnp.int32), k)
-    valid = flat_in >= 0
-    if row_nz is not None:
-        valid &= jnp.take(row_nz, jnp.maximum(flat_in, 0))
-
-    counts = jnp.bincount(jnp.where(valid, taps, k), length=k + 1)[:k]
-    if schedule:
-        sched = _rulebook.tap_schedule(counts)          # tap ids, hot first
-    else:
-        sched = jnp.arange(k, dtype=jnp.int32)
-    srank = jnp.zeros((k,), jnp.int32).at[sched].set(
-        jnp.arange(k, dtype=jnp.int32))                 # tap -> schedule rank
-
-    # group key: output block major, schedule rank minor; invalid at the end
-    gkey = jnp.where(valid, (outs // bo) * k + srank[taps], g_total)
-    counts_g = jnp.bincount(gkey, length=g_total + 1)[:g_total]
+    t = m_pad // bm
     if binning == "argsort":
-        # retained baseline: global stable argsort of the 27N group keys
-        order = jnp.argsort(gkey, stable=True)
-        skey = gkey[order]
-        gstarts = jnp.concatenate([jnp.zeros(1, counts_g.dtype),
-                                   jnp.cumsum(counts_g)])[:g_total]
-        rank = jnp.arange(n_out * k) - jnp.take(
-            gstarts, jnp.minimum(skey, g_total - 1))
-        src = order
-        src_valid = skey < g_total
+        gather, scatter, svalid, tile_tap, tile_ob = _layout_argsort(
+            kmap, row_nz, bm=bm, bo=bo, schedule=schedule, m_pad=m_pad)
     elif binning == "counting":
-        # sort-free: each output row holds exactly one map per tap, and a
-        # (block, schedule-slot) group is one tap's maps within one block,
-        # so the stable within-group rank of entry (row, tap) is just the
-        # count of valid same-tap entries on earlier rows of the block — a
-        # cumsum over rows, reset at block boundaries. No reordering pass.
-        v2 = valid.reshape(n_out, k).astype(jnp.int32)
-        csum = jnp.cumsum(v2, axis=0)                      # inclusive
-        first_row = (jnp.arange(n_out, dtype=jnp.int32) // bo) * bo
-        carried = jnp.take(csum, jnp.maximum(first_row - 1, 0), axis=0)
-        carried = jnp.where(first_row[:, None] > 0, carried, 0)
-        rank = (csum - v2 - carried).reshape(-1)
-        src = jnp.arange(n_out * k, dtype=jnp.int32)
-        src_valid = valid
+        gather, scatter, svalid, tile_tap, tile_ob = _layout_tile_major(
+            kmap, row_nz, bm=bm, bo=bo, schedule=schedule, m_pad=m_pad)
     else:
         raise ValueError(f"unknown binning mode {binning!r}")
-    # padded group starts; empty output blocks force one all-pad tile on
-    # their leading group so the kernel still opens (zeroes) the block
-    pcounts = ((counts_g + bm - 1) // bm) * bm
-    pc2 = pcounts.reshape(n_blocks, k)
-    pc2 = pc2.at[:, 0].add(jnp.where(pc2.sum(1) == 0, bm, 0))
-    pcounts = pc2.reshape(-1)
-    pstarts = jnp.concatenate([jnp.zeros(1, pcounts.dtype),
-                               jnp.cumsum(pcounts)])
-    if binning == "argsort":
-        gkey_p, flat_p, outs_p, valid_p = (gkey[src], flat_in[src],
-                                           outs[src], valid[src])
-    else:
-        gkey_p, flat_p, outs_p, valid_p = gkey, flat_in, outs, valid
-    slot = jnp.where(src_valid,
-                     jnp.take(pstarts[:g_total],
-                              jnp.minimum(gkey_p, g_total - 1)) + rank,
-                     m_pad)
 
-    gather = jnp.zeros((m_pad,), jnp.int32).at[slot].set(
-        jnp.maximum(flat_p, 0), mode="drop")
-    # drop target for pad/elided slots: n_out_pad sits OUTSIDE every bo-row
-    # output block (blocks tile [0, n_blocks*bo)), so the kernel's in-block
-    # mask always zeroes such slots before the one-hot matmul — their rows
-    # may be unfetched (garbage) VMEM; n_out itself can fall *inside* the
-    # last block when bo does not divide n_out. The XLA paths drop it via
-    # scatter mode="drop" just the same.
-    scatter = jnp.full((m_pad,), n_blocks * bo, jnp.int32).at[slot].set(
-        outs_p, mode="drop")
-    svalid = jnp.zeros((m_pad,), bool).at[slot].set(
-        valid_p, mode="drop")
-
-    t = m_pad // bm
-    tile_starts = jnp.arange(t) * bm
-    grank = jnp.searchsorted(pstarts[1:], tile_starts, side="right")
-    capped = jnp.minimum(grank, g_total - 1)
-    tile_tap = sched[capped % k].astype(jnp.int32)
-    tile_ob = (capped // k).astype(jnp.int32)
     v2 = svalid.reshape(t, bm)
     tile_nz = v2.any(axis=1).astype(jnp.int32)
     tile_first = jnp.concatenate(
@@ -276,6 +205,129 @@ def _build_tap_tiles(kmap, row_nz, *, bm, bo, schedule, binning):
         jnp.int32)
     return (gather, scatter, svalid, tile_tap, tile_nz, tile_ob, tile_first,
             tile_run, grp_skip, grp_contig)
+
+
+def _group_starts(counts_g, *, n_blocks, k, bm):
+    """Padded group starts, (n_blocks*K + 1,). Each (block, schedule-slot)
+    group is padded to a bm multiple; an empty output block forces one
+    all-pad tile on its leading group so the kernel still opens (zeroes)
+    the block."""
+    pc2 = (((counts_g + bm - 1) // bm) * bm).reshape(n_blocks, k)
+    lead = jnp.arange(k) == 0
+    pc2 = pc2 + jnp.where((pc2.sum(1, keepdims=True) == 0) & lead, bm, 0)
+    return jnp.concatenate([jnp.zeros(1, pc2.dtype),
+                            jnp.cumsum(pc2.reshape(-1))])
+
+
+def _tile_groups(pstarts, sched, *, k, bm, m_pad):
+    """Per m-tile: its group (clamped to the last), tap and output block.
+    Tiles past the last group's padding belong to no group; they come out
+    as the last group's, holding only pad slots."""
+    g_total = pstarts.shape[0] - 1
+    tile_starts = jnp.arange(m_pad // bm) * bm
+    grank = jnp.searchsorted(pstarts[1:], tile_starts, side="right")
+    capped = jnp.minimum(grank, g_total - 1)
+    tile_tap = sched[capped % k].astype(jnp.int32)
+    tile_ob = (capped // k).astype(jnp.int32)
+    return capped, tile_tap, tile_ob
+
+
+def _layout_tile_major(kmap, row_nz, *, bm, bo, schedule, m_pad):
+    """Default layout: every slot is placed by reading, with no scatter
+    and no per-map gather over the n_out*K map stream.
+
+    Counts come from dense reductions over a (block, tap, row) view of the
+    kmap. Every m-tile is single-(block, tap) by construction, so tile t
+    fetches its group's bo-long column once and resolves each of its bm
+    slots by compare-and-select: slot p0 + j takes the unique row whose
+    valid map has within-group rank p0 + j + 1.
+    """
+    n_out, k = kmap.shape
+    n_blocks = -(-n_out // bo)
+    g_total = n_blocks * k
+    # cols[b, tap, i] = kmap[b*bo + i, tap]; rows past n_out hold -1
+    cols = jnp.pad(kmap, ((0, n_blocks * bo - n_out), (0, 0)),
+                   constant_values=-1)
+    cols = cols.reshape(n_blocks, bo, k).transpose(0, 2, 1)
+    valid = cols >= 0
+    if row_nz is not None:
+        valid &= jnp.take(row_nz, jnp.maximum(cols, 0))
+    # each output row holds exactly one map per tap, so a map's stable rank
+    # within its (block, tap) group is the count of valid entries on earlier
+    # rows of the block: an inclusive cumsum along the column
+    csum = jnp.cumsum(valid.astype(jnp.int32), axis=-1)
+    counts_bt = csum[..., -1]                           # (n_blocks, K)
+    if schedule:
+        sched = _rulebook.tap_schedule(counts_bt.sum(0))  # tap ids, hot first
+    else:
+        sched = jnp.arange(k, dtype=jnp.int32)
+    counts_g = jnp.take(counts_bt, sched, axis=1).reshape(-1)
+    pstarts = _group_starts(counts_g, n_blocks=n_blocks, k=k, bm=bm)
+    capped, tile_tap, tile_ob = _tile_groups(pstarts, sched, k=k, bm=bm,
+                                             m_pad=m_pad)
+
+    col = tile_ob * k + tile_tap               # row of the (g_total, bo) views
+    rank = ((jnp.arange(m_pad // bm) * bm - pstarts[capped])[:, None]
+            + jnp.arange(1, bm + 1)[None, :])                   # (T, bm)
+    key = jnp.where(valid, csum, 0).reshape(g_total, bo)[col]   # (T, bo)
+    src = cols.reshape(g_total, bo)[col]
+    hit = key[:, None, :] == rank[:, :, None]                   # (T, bm, bo)
+    gather = jnp.where(hit, src[:, None, :], 0).sum(-1)
+    row = jnp.where(hit, jnp.arange(bo)[None, None, :], 0).sum(-1)
+    svalid = rank <= counts_g[capped][:, None]
+    # drop target for pad/elided slots: n_out_pad sits OUTSIDE every bo-row
+    # output block (blocks tile [0, n_blocks*bo)), so the kernel's in-block
+    # mask always zeroes such slots before the one-hot matmul — their rows
+    # may be unfetched (garbage) VMEM; n_out itself can fall *inside* the
+    # last block when bo does not divide n_out. The XLA paths drop it via
+    # scatter mode="drop" just the same.
+    scatter = jnp.where(svalid, tile_ob[:, None] * bo + row, n_blocks * bo)
+    return (gather.reshape(-1), scatter.reshape(-1), svalid.reshape(-1),
+            tile_tap, tile_ob)
+
+
+def _layout_argsort(kmap, row_nz, *, bm, bo, schedule, m_pad):
+    """Retained baseline and oracle of :func:`_layout_tile_major`: bincounts
+    of the per-map (block, schedule rank) keys, a global stable argsort of
+    them, then three permutation scatters over the slot budget."""
+    n_out, k = kmap.shape
+    n_blocks = -(-n_out // bo)
+    g_total = n_blocks * k
+    flat_in = kmap.reshape(-1)
+    taps = jnp.tile(jnp.arange(k, dtype=jnp.int32), n_out)
+    outs = jnp.repeat(jnp.arange(n_out, dtype=jnp.int32), k)
+    valid = flat_in >= 0
+    if row_nz is not None:
+        valid &= jnp.take(row_nz, jnp.maximum(flat_in, 0))
+
+    counts = jnp.bincount(jnp.where(valid, taps, k), length=k + 1)[:k]
+    if schedule:
+        sched = _rulebook.tap_schedule(counts)          # tap ids, hot first
+    else:
+        sched = jnp.arange(k, dtype=jnp.int32)
+    srank = jnp.zeros((k,), jnp.int32).at[sched].set(
+        jnp.arange(k, dtype=jnp.int32))                 # tap -> schedule rank
+    # group key: output block major, schedule rank minor; invalid at the end
+    gkey = jnp.where(valid, (outs // bo) * k + srank[taps], g_total)
+    counts_g = jnp.bincount(gkey, length=g_total + 1)[:g_total]
+    order = jnp.argsort(gkey, stable=True)
+    skey = gkey[order]
+    gstarts = jnp.concatenate([jnp.zeros(1, counts_g.dtype),
+                               jnp.cumsum(counts_g)])[:g_total]
+    capped = jnp.minimum(skey, g_total - 1)
+    rank = jnp.arange(n_out * k) - jnp.take(gstarts, capped)
+    pstarts = _group_starts(counts_g, n_blocks=n_blocks, k=k, bm=bm)
+    slot = jnp.where(skey < g_total, jnp.take(pstarts, capped) + rank, m_pad)
+
+    gather = jnp.zeros((m_pad,), jnp.int32).at[slot].set(
+        jnp.maximum(flat_in[order], 0), mode="drop")
+    scatter = jnp.full((m_pad,), n_blocks * bo, jnp.int32).at[slot].set(
+        outs[order], mode="drop")
+    svalid = jnp.zeros((m_pad,), bool).at[slot].set(valid[order],
+                                                    mode="drop")
+    _, tile_tap, tile_ob = _tile_groups(pstarts, sched, k=k, bm=bm,
+                                        m_pad=m_pad)
+    return gather, scatter, svalid, tile_tap, tile_ob
 
 
 def tile_liveness(tiles: TapTiles, row_nz: jnp.ndarray) -> jnp.ndarray:

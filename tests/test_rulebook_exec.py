@@ -324,35 +324,92 @@ def test_schedule_off_keeps_tap_order():
 # Sort-free plan build: counting layout == argsort layout, zero sort ops
 # ---------------------------------------------------------------------------
 
-@forall(8)
-def test_tap_tiles_counting_matches_argsort_bit_exact(rng):
-    """The closed-form counting layout must reproduce the argsort layout
-    bit for bit across bm/bo/schedule combinations — every TapTiles field,
-    including the run metadata the kernel's DMAs key off."""
-    from repro.core import binning
-    n_out = int(rng.integers(8, 64))
-    k = int(rng.choice([8, 27]))
-    bm = int(rng.choice([8, 16]))
-    bo = int(rng.choice([8, 16, 128, 512]))
-    schedule = bool(rng.integers(0, 2))
-    kmap = rng.integers(-1, n_out, size=(n_out, k)).astype(np.int32)
-    kmap[:, int(rng.integers(0, k))] = rng.integers(0, n_out, n_out)
-    t_cnt = sg_ops.build_tap_tiles(jnp.asarray(kmap), bm=bm, bo=bo,
-                                   schedule=schedule, binning="counting")
-    t_arg = sg_ops.build_tap_tiles(jnp.asarray(kmap), bm=bm, bo=bo,
-                                   schedule=schedule, binning="argsort")
+def _assert_tiles_bit_exact(kmap, row_nz=None, **kw):
+    t_cnt = sg_ops.build_tap_tiles(jnp.asarray(kmap), row_nz,
+                                   binning="counting", **kw)
+    t_arg = sg_ops.build_tap_tiles(jnp.asarray(kmap), row_nz,
+                                   binning="argsort", **kw)
     for name, x, y in zip(t_cnt._fields, t_cnt, t_arg):
         if name == "bo":
             assert x == y
         else:
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
-                                          err_msg=(name, bm, bo, schedule))
+                                          err_msg=(name, kw))
+
+
+def _bucket_like_kmap(rng, k):
+    """(1900, k) kmap at bucket-like blocking (bo=512): the row count is
+    no multiple of bo, output block 1 is wholly empty, rows from 1700 on
+    are an all -1 padded tail, and the middle tap is dense so its groups
+    span several bm=128 tiles."""
+    n_out = 1900
+    kmap = np.where(rng.random((n_out, k)) < 0.35,
+                    rng.integers(0, 1700, (n_out, k)), -1).astype(np.int32)
+    kmap[:, k // 2] = np.arange(n_out)
+    kmap[512:1024] = -1
+    kmap[1700:] = -1
+    return kmap
+
+
+@pytest.mark.parametrize("case", ["random", "bucket_k27", "bucket_k8",
+                                  "bucket_k27_row_nz", "bucket_k8_row_nz"])
+def test_tap_tiles_counting_matches_argsort_bit_exact(case):
+    """The default tile-major layout must reproduce the argsort layout bit
+    for bit — every TapTiles field, including the run metadata the
+    kernel's DMAs key off: over random small bm/bo/schedule combinations,
+    and at the cells' blocking (bm=128, bo=512) with a ragged last block,
+    an empty output block and a padded tail, with and without SPAC row
+    elision."""
+    if case == "random":
+        for seed in range(8):
+            rng = np.random.default_rng(seed)
+            n_out = int(rng.integers(8, 64))
+            k = int(rng.choice([8, 27]))
+            kmap = rng.integers(-1, n_out, size=(n_out, k)).astype(np.int32)
+            kmap[:, int(rng.integers(0, k))] = rng.integers(0, n_out, n_out)
+            _assert_tiles_bit_exact(
+                kmap, bm=int(rng.choice([8, 16])),
+                bo=int(rng.choice([8, 16, 128, 512])),
+                schedule=bool(rng.integers(0, 2)))
+        return
+    k = 27 if case.startswith("bucket_k27") else 8
+    rng = np.random.default_rng(k)
+    kmap = _bucket_like_kmap(rng, k)
+    row_nz = (jnp.asarray(rng.random(kmap.shape[0]) < 0.8)
+              if case.endswith("row_nz") else None)
+    _assert_tiles_bit_exact(kmap, row_nz, bm=128, bo=512)
+
+
+def _walk(jaxpr):
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
+def _map_stream_scatters_and_gathers(fn, n_stream, m_pad, *args):
+    """(scatters, gathers) over the map stream in ``fn``'s jaxpr: scatter
+    or scatter-add primitives with an operand of ``n_stream`` (n_out*K) or
+    ``m_pad`` elements, and 1-D gathers whose index stream has
+    ``n_stream`` elements."""
+    scatters = gathers = 0
+    for jpr in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
+        for eqn in jpr.eqns:
+            sizes = [int(np.prod(v.aval.shape)) for v in eqn.invars]
+            if eqn.primitive.name.startswith("scatter"):
+                scatters += any(n in (n_stream, m_pad) for n in sizes)
+            elif eqn.primitive.name == "gather":
+                gathers += (eqn.invars[0].aval.ndim == 1
+                            and sizes[1] == n_stream)
+    return scatters, gathers
 
 
 def test_plan_build_contains_zero_sort_ops():
     """Acceptance audit: build_tap_tiles and every map-search unique pass
-    of the default plan path emit no XLA ``sort`` primitive; the retained
-    argsort baseline emits one, proving the audit bites."""
+    of the default plan path emit no XLA ``sort`` primitive, and the
+    default tile build no scatter, scatter-add or per-map 1-D gather over
+    the map stream (at a small shape and at a ScanNet cell's); the
+    retained argsort baseline emits all three, proving the audit bites."""
     from repro.core import binning
     rng = np.random.default_rng(13)
     kmap = jnp.asarray(rng.integers(-1, 32, size=(32, 27)), jnp.int32)
@@ -362,6 +419,18 @@ def test_plan_build_contains_zero_sort_ops():
         km, None, bm=8, bo=16, schedule=True, binning="argsort")
     assert binning.sort_op_count(counting, kmap) == 0
     assert binning.sort_op_count(argsort, kmap) > 0
+
+    for shape, bm, bo in [((32, 27), 8, 16), ((40960, 27), 128, 512)]:
+        km = jax.ShapeDtypeStruct(shape, jnp.int32)
+        n_stream = shape[0] * shape[1]
+        m_pad = sg_ops._padded_budget(*shape, bm, bo)
+        build = lambda km, binning: sg_ops._build_tap_tiles(
+            km, None, bm=bm, bo=bo, schedule=True, binning=binning)
+        assert _map_stream_scatters_and_gathers(
+            lambda km: build(km, "counting"), n_stream, m_pad, km) == (0, 0)
+        scatters, gathers = _map_stream_scatters_and_gathers(
+            lambda km: build(km, "argsort"), n_stream, m_pad, km)
+        assert scatters > 0 and gathers > 0, (shape, scatters, gathers)
 
     # full default subm3 plan build (octent search + tiles), under trace
     coords, bidx, valid = random_cloud(rng, 32, extent=20, batch=2)

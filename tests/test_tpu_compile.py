@@ -1,4 +1,5 @@
-"""The two Pallas kernels compile for a TPU v5e at Seg(i) size.
+"""The two Pallas kernels compile for a TPU v5e at Seg(i) size, and the
+tile build at the benchmark cells' buckets.
 
 Compiles ahead of time for a described (not attached) ``v5e:2x2`` chip,
 so a kernel that Mosaic would refuse fails here, on a CPU host, before
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.octent.kernel import octent_query
+from repro.kernels.spconv_gemm import ops as sg_ops
 from repro.kernels.spconv_gemm.kernel import spconv_gemm_fused
 
 N = 16_384          # Seg(i) voxels (benchmarks/common.py)
@@ -88,3 +90,16 @@ def test_fused_gemm_compiles_for_v5e(one_chip, cin, cout, bk, epilogue):
 
     compiled = jax.jit(f).lower(*args).compile()
     assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("n,k", [(40_960, 27), (40_960, 8), (69_632, 27),
+                                 (69_632, 8)])
+def test_tile_build_compiles_for_v5e(one_chip, n, k):
+    """The default tile build at the ScanNet (40,960) and SemanticKITTI
+    (69,632) buckets, Subm3 (27 taps) and strided (8 taps). Its
+    compare-and-select over (tiles, bm, bo) must fuse into its reductions:
+    materialised it would be 2.8-4.8 GB."""
+    build = jax.jit(lambda km: sg_ops._build_tap_tiles(
+        km, None, bm=BM, bo=BO, schedule=True, binning="counting"))
+    compiled = build.lower(_spec(one_chip, (n, k))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
