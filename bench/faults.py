@@ -1,35 +1,12 @@
 """Ways to break the served path underneath a run, for the checks that
-``correct`` must fail (test_bench_faults.py, readings.py).
+``correct`` must fail (test_bench_faults.py, readings.py). The control,
+the family's reference in the program's place, is the family's own
+``control`` hook.
 
 Each is an ``on_engine(engine)`` hook for :func:`run.run_cell`; the ones
 that patch a module attribute return a callable that undoes it.
 """
 from __future__ import annotations
-
-import numpy as np
-
-import geometry
-import reference
-
-
-def control(arch: reference.Arch):
-    """The reference, at the next precision down, in the program's place:
-    every request of the run is answered by it."""
-    def hook(engine):
-        def forward_fn(params, st, plans):
-            import jax.numpy as jnp
-            valid = np.asarray(st.valid)
-            n = int(valid.sum())
-            coords = np.asarray(st.coords)[:n]
-            feats = np.asarray(st.feats)[:n]
-            out = reference.forward(
-                arch, params, feats, geometry.hierarchy(coords, len(arch.enc)),
-                valid.shape[0], precision="bf16x3")
-            full = np.zeros((valid.shape[0], out.shape[1]), np.float32)
-            full[:n] = out
-            return jnp.asarray(full)
-        engine._forward_fn = forward_fn
-    return hook
 
 
 def alter_answer(delta: float = 1e-2):
@@ -45,15 +22,15 @@ def alter_answer(delta: float = 1e-2):
 
 def swap_answers(engine):
     """The first two answers of every tick handed to each other's client."""
-    run_batch = engine._execute_batch
+    step = engine.step
 
-    def execute_batch(reqs):
-        res = run_batch(reqs)
-        done = [r for r in res if r.logits is not None]
+    def swapped():
+        res = step()
+        done = [r for r in res if r.status == "completed"]
         if len(done) >= 2:
-            done[0].logits, done[1].logits = done[1].logits, done[0].logits
+            done[0].rid, done[1].rid = done[1].rid, done[0].rid
         return res
-    engine._execute_batch = execute_batch
+    engine.step = swapped
 
 
 def drop_tap(tap: int = 0):

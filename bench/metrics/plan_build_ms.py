@@ -1,4 +1,5 @@
-"""Host milliseconds per cloud inside ``minkunet.build_plans``."""
+"""Host milliseconds per cloud inside the program's plan build: the entry
+that the family's ``serve`` names as ``plan_build``."""
 
 
 def read(ctx):

@@ -2,7 +2,7 @@
 
 The program marks its phases with host spans named ``serve.*`` and
 ``plan.*`` (``jax.profiler.TraceAnnotation`` in ``launch/spconv_serve.py``,
-``models/minkunet.build_plans`` and ``core/plan.py``). This module reads
+a model's ``build_plans`` and ``core/plan.py``). This module reads
 the ``.xplane.pb`` that ``run.py`` leaves under
 ``<checkout>/.bench_run/trace`` while the per-layer readers run, and puts
 each device op down to the span that launched it:

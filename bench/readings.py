@@ -6,9 +6,9 @@
 
 In one process: the program's runs over ``--seeds`` seeds (the lower
 reading is the largest ``max_rel_err`` they give), then the control's —
-the plain reference at bf16x3 answering every request in the program's
-place — over ``--control-seeds`` (the upper reading is the smallest),
-and with ``--faults`` the planted faults of faults.py. Each run is a
+the family's plain reference at bf16x3 answering every request in the
+program's place (its ``control`` hook) — over ``--control-seeds`` (the
+upper reading is the smallest), and with ``--faults`` the planted faults of faults.py. Each run is a
 whole run of the harness at the cell's size with a short window. Prints
 one JSON line per run and a summary last. Not run by the benchmark.
 """
@@ -24,7 +24,6 @@ if HERE not in sys.path:
     sys.path.insert(0, HERE)
 
 import faults  # noqa: E402
-import reference  # noqa: E402
 import run  # noqa: E402
 
 
@@ -58,10 +57,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     spec = run.cell_spec(run.ROOT, args.workload)
     run.setup_jax(run.ROOT)
-    arch = reference.arch(spec["config"])
+    fam = spec["family"]
+    arch = fam.arch(spec["config"])
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
     prog = [reading(spec, s, args.seconds, "program") for s in seeds]
-    ctrl = [reading(spec, s, args.seconds, "control", faults.control(arch))
+    ctrl = [reading(spec, s, args.seconds, "control", fam.control(arch))
             for s in seeds[:args.control_seeds]]
     def ok(xs):
         return [x for x in xs if x is not None]

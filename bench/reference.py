@@ -137,9 +137,11 @@ def dot_highest(x, w):
 
 
 def _split(x):
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
+    """``x`` as bf16 ``hi + lo``. ``hi`` is rounded by ``reduce_precision``,
+    which XLA keeps: a float32 -> bf16 -> float32 round trip may be folded
+    away (excess precision is allowed on the TPU), which leaves ``lo`` 0."""
+    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
 
 
 def dot_bf16x3(x, w):

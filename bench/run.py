@@ -1,30 +1,34 @@
 #!/usr/bin/env python3
-"""Benchmark harness: MinkUNet served to closed-loop clients on the chip.
+"""Benchmark harness: sparse 3D networks served to closed-loop clients on
+the chip.
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything about a cell is found by name from ``BENCHMARK.json`` at the
 root of the checkout: the configuration's file (``configs/<name>.json``),
-the traffic mix (``traffic/<name>.json``) and, in a traced run, one
-reader per per-layer metric (``metrics/<name>.py``, a ``read(ctx)``
-function). Adding a cell, a configuration, a traffic mix or a metric
-adds files and entries and edits none.
+its model family (``families/<family>.py``, named by the file's
+``family``), the traffic mix (``traffic/<name>.json``) and, in a traced
+run, one reader per per-layer metric (``metrics/<name>.py``, a
+``read(ctx)`` function). Adding a cell, a configuration, a traffic mix, a
+metric or a model family adds files and entries and edits none: past
+``cell_spec`` the harness knows a model only through its family's hooks
+(``FAMILY_HOOKS``).
 
 A run has three phases:
 
 1. Set-up (``setup_s``, from process start): weights from ``--seed`` on
-   the device in one call, the traffic's base scenes, one ``ServeEngine``
+   the device in one call, the traffic's base scenes, the family's engine
    with the traffic's single padding bucket, and one warm-up tick that
    compiles the bucket's executable and the eager plan-build programs.
 2. The window: ``clients`` closed-loop clients each submit a fresh cloud
    and send the next one as soon as the previous answer is back, through
-   ``ServeEngine.submit``/``step``, until ``--seconds`` have passed; the
+   the engine's ``submit``/``step``, until ``--seconds`` have passed; the
    requests then in flight are answered and counted. With ``--trace 1``
    the window runs under the JAX profiler, and the harness marks its own
    spans (engine tick, plan build, dispatch, client) in the trace.
 3. The check: a sample of the window's answers, drawn from the seed,
-   against the plain float32 reference (``reference.py``) on the same
-   coordinates and weights, after the engine has been freed.
+   against the family's plain float32 reference on the same coordinates
+   and weights, after the engine has been freed.
 
 The last line of stdout is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown``
@@ -44,6 +48,7 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -55,13 +60,22 @@ if HERE not in sys.path:
     sys.path.insert(0, HERE)
 sys.path.insert(1, os.path.join(ROOT, "src"))
 
-import counts  # noqa: E402
-import geometry  # noqa: E402
-import reference  # noqa: E402
 import scenes  # noqa: E402
 import trace_reduce  # noqa: E402
 
-KERNELS = ("octent_query", "spconv_gemm_fused")
+#: what a family module ``families/<family>.py`` provides: ``arch(cfg)``;
+#: ``init_params(arch, seed)``; ``serve(arch, cfg, params, *, bucket,
+#: clients, impl)`` -> ``{"engine", "plan_build", "dispatch"}``, the last
+#: two the ``(owner, attribute)`` entry points the harness wraps;
+#: ``answer(result)``; ``reference(arch, params, coords, feats, bucket, *,
+#: precision)``, whose answer lies in the served answer's leading rows;
+#: ``cloud_work(arch, coords, peaks)``; ``kernels``. ``control(arch)``
+#: (readings.py) and ``rehearse(...)`` (rehearse_compile.py) serve the
+#: tools beside the harness.
+FAMILY_HOOKS = ("arch", "init_params", "serve", "answer", "reference",
+                "cloud_work", "kernels")
+#: a family's name becomes a file name
+FAMILY_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 #: health counters whose movement means a request was not served as
 #: configured: a kernel answered by its oracle, a quarantine, the
 #: degradation ladder, a shed, rejected or isolated request
@@ -111,6 +125,7 @@ def cell_spec(root: str, workload: str) -> dict:
         raise SystemExit(f"unknown workload {workload!r}")
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config = load_json(os.path.join(root, conf["file"]))
+    family = load_family(root, cell["config"], config)
     traffic = load_json(os.path.join(root, "bench", "traffic",
                                      cell["traffic"] + ".json"))
     # the configuration states its input scale (voxel size, crop); a
@@ -127,6 +142,7 @@ def cell_spec(root: str, workload: str) -> dict:
     return {
         "cell": cell,
         "config": config,
+        "family": family,
         "traffic": traffic,
         "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
         "per_layer": [m for m in bench["per_layer"] if mine(m)],
@@ -134,13 +150,43 @@ def cell_spec(root: str, workload: str) -> dict:
     }
 
 
-def metric_reader(root: str, name: str):
-    path = os.path.join(root, "bench", "metrics", name + ".py")
+def load_module(path: str, prefix: str, name: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_family(root: str, config_name: str, config: dict):
+    """The module ``bench/families/<family>.py`` that the configuration
+    names; a configuration with no family, or one that names a family
+    with no module or with hooks missing, is refused."""
+    name = config.get("family")
+    if not name:
+        raise SystemExit(f"configuration {config_name!r} names no model "
+                         f'family: its file needs "family": "<name>", '
+                         f"with a module bench/families/<name>.py")
+    if not isinstance(name, str) or not FAMILY_NAME.fullmatch(name):
+        raise SystemExit(f"configuration {config_name!r} names family "
+                         f"{name!r}, which is not the plain name of a "
+                         f"module under bench/families/")
+    path = os.path.join(root, "bench", "families", name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"configuration {config_name!r} names family "
+                         f"{name!r}, which has no module "
+                         f"bench/families/{name}.py")
+    mod = load_module(path, "bench_family_", name)
+    missing = [h for h in FAMILY_HOOKS if not hasattr(mod, h)]
+    if missing:
+        raise SystemExit(f"configuration {config_name!r} names family "
+                         f"{name!r}, whose module lacks {missing}")
+    return mod
+
+
+def metric_reader(root: str, name: str):
+    path = os.path.join(root, "bench", "metrics", name + ".py")
+    return load_module(path, "bench_metric_", name).read
 
 
 def device_peaks(kind: str) -> dict | None:
@@ -175,13 +221,15 @@ class Compiles:
 
 class Spans:
     """Harness spans in the profiler's trace, and host time in the plan
-    build, around the program's own entry points."""
+    build, around the program entry points that the family's ``serve``
+    names: ``plan_build`` and ``dispatch``, each ``(owner, attribute)``."""
 
-    def __init__(self, jax, engine, minkunet):
+    def __init__(self, jax, served: dict):
         self.plan_s = 0.0
         self.plan_n = 0
         self.ann = jax.profiler.TraceAnnotation
-        build = minkunet.build_plans
+        owner, attr = served["plan_build"]
+        build = getattr(owner, attr)
 
         def build_plans(*a, **k):
             t = time.perf_counter()
@@ -191,19 +239,20 @@ class Spans:
             self.plan_n += 1
             return out
 
-        self._undo = (minkunet, build)
-        minkunet.build_plans = build_plans
-        fwd = engine._forward_fn
+        self._undo = (owner, attr, build)
+        setattr(owner, attr, build_plans)
+        owner, attr = served["dispatch"]
+        fwd = getattr(owner, attr)
 
         def forward_fn(*a, **k):
             with self.ann("bench.dispatch"):
                 return fwd(*a, **k)
 
-        engine._forward_fn = forward_fn
+        setattr(owner, attr, forward_fn)
 
     def close(self) -> None:
-        mod, build = self._undo
-        mod.build_plans = build
+        owner, attr, build = self._undo
+        setattr(owner, attr, build)
 
 
 def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
@@ -214,6 +263,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     import jax
     root = spec["root"]
     cfg, traffic, cell = spec["config"], spec["traffic"], spec["cell"]
+    fam = spec["family"]
     devs = jax.devices()
     if require_chip and (devs[0].platform != "tpu"
                          or len(devs) < cell["chips"]):
@@ -224,9 +274,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     from repro.core import plan as planlib
     from repro.kernels.octent import ops as oct_ops
     from repro.kernels.spconv_gemm import ops as sg_ops
-    from repro.launch.spconv_serve import ServeEngine
-    from repro.models import minkunet
-    from repro.runtime import admission, guard
+    from repro.runtime import guard
 
     impls = {"search": oct_ops.search_impl(), "gemm": sg_ops.kernel_impl()}
     log(f"resolved impls: {impls}")
@@ -238,20 +286,15 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
         raise RuntimeError(f"no peaks for device kind "
                            f"{devs[0].device_kind!r} in peaks.json")
 
-    arch = reference.arch(cfg)
-    params = jax.block_until_ready(reference.init_params(arch, seed))
+    arch = fam.arch(cfg)
+    params = jax.block_until_ready(fam.init_params(arch, seed))
     pool = scenes.base_pool(traffic)
     bucket, clients = int(traffic["bucket"]), int(traffic["clients"])
     stream = scenes.requests(pool, traffic, seed)
-    prog_cfg = minkunet.MinkUNetConfig(
-        name=cfg["name"], in_ch=arch.in_ch, classes=arch.classes,
-        stem=arch.stem, enc=arch.enc, dec=arch.dec, blocks=arch.blocks)
-    queue = admission.AdmissionQueue(buckets=(bucket,),
-                                     grid_bits=prog_cfg.grid_bits,
-                                     batch_bits=prog_cfg.batch_bits)
-    engine = ServeEngine(params, prog_cfg, impl=impls["gemm"], queue=queue,
-                         max_batch=clients)
-    spans = Spans(jax, engine, minkunet)
+    served = fam.serve(arch, cfg, params, bucket=bucket, clients=clients,
+                       impl=impls["gemm"])
+    engine = served["engine"]
+    spans = Spans(jax, served)
     if on_engine is not None:
         on_engine(engine)
     sent: dict = {}
@@ -336,18 +379,18 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     sample = ([largest] if largest else []) + [
         others[i] for i in rng.choice(len(others), max(0, n_check - 1),
                                       replace=False)]
-    served = {rid: done[rid][0].logits for rid in sample}
+    answers = {rid: fam.answer(done[rid][0]) for rid in sample}
     served_bases = [sent[rid]["base"] for rid in ok]
     plan_s, plan_n = spans.plan_s, spans.plan_n
-    del engine, queue, done, spans
+    del served, engine, done, spans
     gc.collect()
     worst = 0.0
     t_chk = time.perf_counter()
     for rid in sample:
         q = sent[rid]
-        hier = geometry.hierarchy(q["coords"], len(arch.enc))
-        want = reference.forward(arch, params, q["feats"], hier, bucket)
-        got = np.asarray(served[rid])[:want.shape[0]]
+        want = fam.reference(arch, params, q["coords"], q["feats"], bucket,
+                             precision="highest")
+        got = np.asarray(answers[rid])[:want.shape[0]]
         err = float(np.abs(got - want).max() / np.abs(want).max())
         if not np.isfinite(err):
             err = float("inf")
@@ -372,22 +415,24 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     breakdown, busy = None, None
     if trace and len(ok) and not plan_n:
         raise RuntimeError("no plan build passed through the harness's "
-                           "span around minkunet.build_plans: the engine "
-                           "no longer builds plans through it")
+                           "span around the family's plan-build entry: "
+                           "the engine no longer builds plans through it")
     if not trace:
         for m in spec["end_to_end"]:
             if e2e[m["name"]] is not None:
                 metrics[m["name"]] = e2e[m["name"]]
     else:
-        summary = trace_reduce.summarize(trace_dir, KERNELS)
+        summary = trace_reduce.summarize(trace_dir, tuple(fam.kernels))
+        # every count of the family's cloud_work, summed over the window's
+        # clouds; the three every family gives start at 0
         work = {"conv_flops": 0, "model_flops": 0, "conv_min_s": 0.0}
         if peaks is not None:
             per_base = {}
             for b in set(served_bases):
-                per_base[b] = counts.cloud_work(arch, pool[b][0], peaks)
+                per_base[b] = fam.cloud_work(arch, pool[b][0], peaks)
             for b in served_bases:
-                for k in work:
-                    work[k] += per_base[b][k]
+                for k, v in per_base[b].items():
+                    work[k] = work.get(k, 0) + v
         ctx = {"trace": summary, "clouds": len(ok), "window_s": window_s,
                "clouds_per_s": e2e["clouds_per_s"], "latency_ms": lat_ms,
                "compiles": compiles_in_window,

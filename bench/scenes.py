@@ -9,11 +9,21 @@ occupies is served.
 A traffic file names a generator and its parameters. The base pool is a
 fixed set of scenes (the same for every ``--seed``, so every run serves
 the same sizes); ``--seed`` draws the order in which clients send them
-and a fresh transform for every request: an x/y flip or swap and a
-translation by whole 16-voxel octree blocks. No two requests share
+and a fresh transform for every request: by default an x/y flip or swap
+and a translation by whole 16-voxel octree blocks. No two requests share
 geometry, yet each does exactly the work of its base scene, because
 stride-2 coarsening commutes with 16-voxel shifts and with flips of an
 extent that is a multiple of 16.
+
+A mix may instead list the transforms it draws (``"transforms"``, out of
+``TRANSFORMS``), in the same draw order. A model whose input grid is fixed
+in absolute coordinates, such as a bird's-eye-view detector, lists only
+``flip_y``: its requests then stay where the scene was voxelized.
+
+``voxel_m`` is one edge for all three axes or a per-axis ``[x, y, z]``
+list. The LiDAR generator crops either to ``crop_half_m`` around the
+sensor above ``z_min_m``, or to an axis-aligned box ``range_m`` =
+``[xmin, ymin, zmin, xmax, ymax, zmax]`` in metres.
 """
 from __future__ import annotations
 
@@ -22,6 +32,9 @@ import numpy as np
 #: voxel coordinates lie in [0, GRID) per axis (16-voxel blocks, 7 bits)
 GRID = 2048
 BLOCK = 16
+#: the request transforms a mix may draw; bits 0-2 of a variant are the
+#: first three, in this order
+TRANSFORMS = ("flip_x", "flip_y", "swap", "shift")
 
 
 def lidar_points(rng: np.random.Generator, *, n_rings: int, az_steps: int,
@@ -83,11 +96,13 @@ def indoor_points(rng: np.random.Generator, *, n_points: int, room: float,
     return np.column_stack([pts, inten])
 
 
-def voxelize(points: np.ndarray, voxel: float, origin, extent):
+def voxelize(points: np.ndarray, voxel, origin, extent):
     """Every occupied voxel of ``points`` inside ``[0, extent)`` per axis:
     ``(coords (V, 3) int32, feats (V, 4) float32)`` with per-voxel mean
-    features (offset inside the voxel, intensity). Never thins."""
+    features (offset inside the voxel, intensity). ``voxel`` is one edge
+    or one per axis. Never thins."""
     origin = np.asarray(origin, np.float64)
+    voxel = np.asarray(voxel, np.float64)
     ijk = np.floor((points[:, :3] - origin) / voxel).astype(np.int64)
     ok = np.all((ijk >= 0) & (ijk < np.asarray(extent)), axis=1)
     ijk, pts = ijk[ok], points[ok]
@@ -127,8 +142,13 @@ def base_scene(traffic: dict, scene_seed: int):
                            max_range=p["max_range_m"],
                            sensor_height=p["sensor_height_m"],
                            n_boxes=tuple(p["boxes"]))
-        half = p["crop_half_m"]
-        origin = (-half, -half, p["z_min_m"])
+        if "range_m" in p:
+            lo, hi = np.split(np.asarray(p["range_m"], np.float64), 2)
+            pts = pts[np.all((pts[:, :3] >= lo) & (pts[:, :3] < hi), axis=1)]
+            origin = tuple(lo)
+        else:
+            half = p["crop_half_m"]
+            origin = (-half, -half, p["z_min_m"])
     else:
         raise ValueError(f"unknown generator {traffic['generator']!r}")
     return voxelize(pts, p["voxel_m"], origin, _extent(traffic))
@@ -167,16 +187,26 @@ def transform(coords: np.ndarray, ext: np.ndarray, variant: int,
 def requests(pool: list, traffic: dict, seed: int):
     """Endless seeded stream of ``(base_index, coords, feats)``: the pool
     in a fresh random order each round, each request under a fresh
-    transform. Same seed, same stream."""
+    transform drawn from the mix's ``transforms`` (all of ``TRANSFORMS``
+    where it lists none; a swap only on a square extent). Same seed, same
+    stream."""
+    drawn = traffic.get("transforms", TRANSFORMS)
+    unknown = set(drawn) - set(TRANSFORMS)
+    if unknown:
+        raise ValueError(f"unknown transforms {sorted(unknown)}; "
+                         f"known: {TRANSFORMS}")
     rng = np.random.default_rng(seed)
     ext = _extent(traffic)
-    span = (GRID - ext) // BLOCK
-    variants = 8 if ext[0] == ext[1] else 4     # a swap needs a square
+    bits = [b for b, name in enumerate(TRANSFORMS[:3]) if name in drawn
+            and (name != "swap" or ext[0] == ext[1])]
+    span = (GRID - ext) // BLOCK if "shift" in drawn else None
     while True:
         for i in rng.permutation(len(pool)):
             c, f = pool[i]
-            v = int(rng.integers(0, variants))
-            shift = [int(rng.integers(0, s + 1)) for s in span]
+            k = int(rng.integers(0, 1 << len(bits))) if bits else 0
+            v = sum(((k >> j) & 1) << b for j, b in enumerate(bits))
+            shift = ([int(rng.integers(0, s + 1)) for s in span]
+                     if span is not None else (0, 0, 0))
             yield int(i), transform(c, ext, v, shift), f
 
 

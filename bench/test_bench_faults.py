@@ -10,7 +10,6 @@ from __future__ import annotations
 import pytest
 
 import faults
-import reference
 import run
 import testkit
 
@@ -53,7 +52,7 @@ def test_control_is_not_correct(workload):
     spec = run.cell_spec(run.ROOT, workload)
     spec["traffic"] = dict(spec["traffic"], **testkit.TINY_TRAFFIC,
                            generator="indoor")
-    arch = reference.arch(spec["config"])
-    out = _run(spec, faults.control(arch))
+    fam = spec["family"]
+    out = _run(spec, fam.control(fam.arch(spec["config"])))
     c = out["checks"]["max_rel_err"]
     assert not out["correct"] and c["value"] > c["limit"], c

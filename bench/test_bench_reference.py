@@ -1,5 +1,6 @@
 """The plain reference against the program's own ``ref`` forward on a
-tiny cloud, and the control's precision."""
+tiny cloud, both through the family's hooks, and the control's
+precision."""
 from __future__ import annotations
 
 import jax
@@ -7,8 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import geometry
 import reference
+import run
 import scenes
 
 TRAFFIC = {"generator": "indoor",
@@ -17,33 +18,38 @@ TRAFFIC = {"generator": "indoor",
            "extent_voxels": [48, 48, 32]}
 
 
-def program_logits(a, params, coords, feats, rows):
-    from repro.models import minkunet
-    cfg = minkunet.MinkUNetConfig(in_ch=a.in_ch, classes=a.classes,
-                                  stem=a.stem, enc=a.enc, dec=a.dec,
-                                  blocks=a.blocks)
-    c, b, v, f = map(jnp.asarray, scenes.padded(coords, feats, rows))
-    plans = minkunet.build_plans(c, b, v, cfg, n_max=rows)
-    from repro.core.spconv import SparseTensor
-    out = minkunet.forward(params, SparseTensor(c, b, v, f), cfg,
-                           plans=plans, impl="ref")
-    return np.asarray(out)[:coords.shape[0]]
+@pytest.fixture(scope="module")
+def spec():
+    return run.cell_spec(run.ROOT, "scannet.fresh-c4")
+
+
+def program_logits(spec, a, params, coords, feats, rows):
+    """The answer of the family's served path, on the ``ref`` backend."""
+    fam = spec["family"]
+    served = fam.serve(a, spec["config"], params, bucket=rows, clients=1,
+                       impl="ref")
+    engine = served["engine"]
+    engine.submit("r", *scenes.padded(coords, feats, rows), deadline_s=600.0)
+    (res,) = engine.step()
+    assert res.status == "completed", res
+    return np.asarray(fam.answer(res))[:coords.shape[0]]
 
 
 @pytest.mark.parametrize("arch", [
     reference.Arch(4, 8, (8, 16), (16, 8), 1, 4),
     reference.Arch(4, 8, (8, 16, 16), (16, 8, 8), 2, 5),
 ], ids=["two-stage", "three-stage"])
-def test_reference_agrees_with_program_ref_forward(arch):
+def test_reference_agrees_with_program_ref_forward(spec, arch):
+    fam = spec["family"]
     coords, feats = scenes.base_scene(TRAFFIC, 3)
     moved = scenes.transform(coords, np.asarray(TRAFFIC["extent_voxels"]),
                              5, (2, 1, 4))
     rows = 4096
     assert moved.shape[0] <= rows
-    params = reference.init_params(arch, 2**33 + 17)
-    want = reference.forward(arch, params, feats,
-                             geometry.hierarchy(moved, len(arch.enc)), rows)
-    got = program_logits(arch, params, moved, feats, rows)
+    params = fam.init_params(arch, 2**33 + 17)
+    want = fam.reference(arch, params, moved, feats, rows,
+                         precision="highest")
+    got = program_logits(spec, arch, params, moved, feats, rows)
     assert np.abs(want).max() > 0.1
     err = np.abs(got - want).max() / np.abs(want).max()
     assert err < 1e-5, err
