@@ -361,35 +361,60 @@ def build_maps_gconv2(coords: jnp.ndarray, batch: jnp.ndarray,
         tap=tap.astype(jnp.int32), mvalid=valid)
 
 
-@partial(jax.jit, static_argnames=("grid_bits", "batch_bits", "out_budget"))
+def strided_out_extent(extent, kernel, stride, padding) -> tuple:
+    """spconv's output extent of a strided conv, per axis:
+    ``(D + 2p - K) // s + 1``."""
+    return tuple((d + 2 * p - k) // s + 1
+                 for d, k, s, p in zip(extent, kernel, stride, padding))
+
+
+@partial(jax.jit, static_argnames=("grid_bits", "batch_bits", "out_budget",
+                                   "kernel", "stride", "padding", "extent"))
+@jax.named_scope("plan.search")
 def build_maps_gconv3(coords: jnp.ndarray, batch: jnp.ndarray,
                       valid: jnp.ndarray, *, grid_bits: int = 7,
                       batch_bits: int = 4,
-                      out_budget: int | None = None) -> StridedMaps:
-    """Gconv3 (k=3, s=2), input-stationary (§IV-D3).
+                      out_budget: int | None = None,
+                      kernel: tuple = (3, 3, 3), stride: tuple = (2, 2, 2),
+                      padding: tuple = (1, 1, 1),
+                      extent: tuple | None = None) -> StridedMaps:
+    """Strided SpConv maps, input-stationary (§IV-D3), with spconv's
+    semantics per axis (x, y, z): input ``i`` feeds output ``o`` through
+    kernel offset ``k`` in ``[0, K)`` iff ``o * s - p + k == i``, and
+    ``o`` is kept where ``0 <= o`` and, given the input ``extent`` D,
+    ``o < (D + 2p - K) // s + 1`` (:func:`strided_out_extent`). Taps are
+    x-fastest, ``kx + Kx * (ky + Ky * kz)``.
 
-    Output site o receives input i through tap d iff 2*o + d == theta_i
-    (d in {-1,0,1}^3). Per dim: even coord -> d=0 only; odd -> d=+-1, so each
-    input emits at most 8 (out, tap) candidates — enumerated statically.
+    Per axis only offsets ``k = (i + p) mod s + j * s`` divide, so each
+    input emits ``prod(ceil(K / s))`` (out, tap) candidates, enumerated
+    statically: 8 for a 3x3x3 stride-2 conv, 2 for a 3-tap z-only one.
+    The defaults (k3 s2 p1, no extent) are the paper's Gconv3,
+    ``2 * o + d == i`` with ``d = k - 1``.
     """
     n = coords.shape[0]
-    choice = jnp.array([[(c >> 0) & 1, (c >> 1) & 1, (c >> 2) & 1]
-                        for c in range(8)], dtype=jnp.int32)    # (8, 3)
-    odd = (coords & 1).astype(jnp.int32)                         # (N, 3)
-    d = jnp.where(odd[:, None, :] == 1, 2 * choice[None] - 1,
-                  jnp.zeros((1, 1, 3), jnp.int32))               # (N, 8, 3)
-    cand_ok = jnp.all((odd[:, None, :] == 1) | (choice[None] == 0), axis=-1)
-    out = (coords[:, None, :] - d) >> 1                          # (N, 8, 3)
+    k_ax = np.asarray(kernel, np.int32)
+    s_ax = np.asarray(stride, np.int32)
+    p_ax = np.asarray(padding, np.int32)
+    per_axis = [range(-(-int(k) // int(s))) for k, s in zip(k_ax, s_ax)]
+    j = np.array([(jx, jy, jz) for jz in per_axis[2] for jy in per_axis[1]
+                  for jx in per_axis[0]], np.int32)              # (C, 3)
+    k = (coords + p_ax) % s_ax                                   # (N, 3)
+    k = k[:, None, :] + j[None] * s_ax                           # (N, C, 3)
+    out = (coords[:, None, :] + p_ax - k) // s_ax                # exact
+    cand_ok = jnp.all((k < k_ax) & (out >= 0), axis=-1)
+    if extent is not None:
+        hi = np.asarray(strided_out_extent(extent, kernel, stride, padding))
+        cand_ok &= jnp.all(out < hi, axis=-1)
     cand_ok = cand_ok & valid[:, None]
-    tap = (d[..., 0] + 1) + 3 * (d[..., 1] + 1) + 9 * (d[..., 2] + 1)
+    tap = k[..., 0] + k_ax[0] * (k[..., 1] + k_ax[1] * k[..., 2])
 
     ob = jnp.broadcast_to(batch[:, None], out.shape[:2])
     hi = morton.block_key(out.reshape(-1, 3), ob.reshape(-1), grid_bits, batch_bits)
     lo = morton.local_code(out.reshape(-1, 3))
     ok_flat = cand_ok.reshape(-1)
-    m = ok_flat.shape[0]                                         # 8N candidates
+    m = ok_flat.shape[0]                                 # C * N candidates
     # Static output budget: downsampled outputs number <= inputs in real
-    # clouds, so callers cap the 8N candidate space. Truncation is NOT
+    # clouds, so callers cap the candidate space. Truncation is NOT
     # silent: ``n_true`` reports the true unique-output count and
     # ``overflow`` flags n_true > budget, which plan.gconv3_plan
     # surfaces exactly like the octree block-table overflow (eager
@@ -405,7 +430,7 @@ def build_maps_gconv3(coords: jnp.ndarray, batch: jnp.ndarray,
         out_coords=out_coords, out_batch=out_batch, out_valid=okv,
         n_out=jnp.minimum(n_out, budget),
         in_idx=jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None],
-                                (n, 8)).reshape(-1),
+                                (n, j.shape[0])).reshape(-1),
         out_idx=jnp.where(ok_flat, rank, 0).astype(jnp.int32),
         tap=tap.reshape(-1).astype(jnp.int32), mvalid=ok_flat,
         n_true=n_true, overflow=n_true > budget)

@@ -229,6 +229,10 @@ class ConvPlan(NamedTuple):
                                          # candidate budget (set under jit;
                                          # eager builds raise
                                          # validate.CapacityOverflow)
+    sites: int | None = None  # gconv3: the true output-site count, read
+                              # by the eager capacity check (None under
+                              # jit); a Python int, so strip it before
+                              # passing the plan through jit as arguments
 
     @property
     def residency(self) -> dict:
@@ -492,31 +496,28 @@ def _require_block_capacity(n_blocks, max_blocks: int):
 
 def _require_out_capacity(overflow_flag, n_true, budget: int):
     """Surface Gconv3 candidate-space overflow (the mapsearch.py
-    truncation sibling of :func:`_require_block_capacity`): eagerly this
-    raises :class:`~repro.core.validate.CapacityOverflow`; under jit the
-    () bool flag is returned and carried on ``ConvPlan.overflow``."""
+    truncation sibling of :func:`_require_block_capacity`). Returns
+    ``(overflow, sites)``: eagerly ``sites`` is the true output-site
+    count, read in the one host read of the check, and an overflow raises
+    :class:`~repro.core.validate.CapacityOverflow`; under jit ``sites``
+    is None and the () bool flag is carried on ``ConvPlan.overflow``."""
     overflow = jnp.asarray(overflow_flag, bool)
     try:
         with TraceAnnotation("plan.check"):
-            concrete = bool(overflow)
-    except jax.errors.ConcretizationTypeError:
-        return overflow
+            sites = int(n_true)
+    except (jax.errors.ConcretizationTypeError,
+            jax.errors.TracerIntegerConversionError):
+        return overflow, None
     guard.health().note("plan.host_sync")
-    if concrete:
-        try:
-            needed = int(n_true)
-            guard.health().note("plan.host_sync")
-        except (TypeError, jax.errors.ConcretizationTypeError):
-            needed = None
+    if sites > budget:
         raise validate.CapacityOverflow(
             "candidates",
             f"gconv3 candidate budget overflow: the cloud produces "
-            f"{needed if needed is not None else '> budget'} downsampled "
-            f"output sites but out_budget={budget}; the overflowing sites "
-            f"would silently lose their maps — raise out_budget (or wrap "
-            f"the build in runtime/guard.with_replan)",
-            needed=needed, capacity=budget)
-    return overflow
+            f"{sites} downsampled output sites but out_budget={budget}; "
+            f"the overflowing sites would silently lose their maps — raise "
+            f"out_budget (or wrap the build in runtime/guard.with_replan)",
+            needed=sites, capacity=budget)
+    return overflow, sites
 
 
 class SubmWarmStart(NamedTuple):
@@ -688,31 +689,48 @@ def gconv3_plan(coords, batch, valid, *, grid_bits: int = 7,
                 batch_bits: int = 4, out_budget: int | None = None,
                 bm: int = 128, bo: int | None = None,
                 with_tiles: bool = True,
+                kernel: tuple = (3, 3, 3), stride: tuple = (2, 2, 2),
+                padding: tuple = (1, 1, 1), extent: tuple | None = None,
                 cache: PlanCache | None = None) -> ConvPlan:
-    """Gconv3 (k=3, s=2) plan (§IV-D3). Carries the scatter maps so the
+    """Strided conv plan (§IV-D3): by default Gconv3 (k=3, s=2, p=1);
+    ``kernel``, ``stride``, ``padding`` (per axis, x y z) and the input
+    ``extent`` give any strided conv spconv's maps and output-extent crop
+    (``mapsearch.build_maps_gconv3``). Carries the scatter maps so the
     input-stationary dataflow can execute from the same plan;
     ``with_tiles=False`` skips the tile build for that dataflow (the tiles
     would be dead weight — it consumes only ``plan.maps``). ``with_tiles``
     is part of the cache key, so a rare mixed-dataflow reuse of one
     coordinate set costs a second search rather than returning a plan
-    without the tiles the output-stationary path needs."""
+    without the tiles the output-stationary path needs. An eager build
+    carries its output-site count on ``ConvPlan.sites``, from the capacity
+    check's own host read."""
     budget = out_budget if out_budget is not None else coords.shape[0]
-    statics = ("gconv3", grid_bits, batch_bits, budget, bm, bo, with_tiles)
+    kernel, stride, padding = tuple(kernel), tuple(stride), tuple(padding)
+    extent = None if extent is None else tuple(int(d) for d in extent)
+    n_taps = int(np.prod(kernel))
+    statics = ("gconv3", grid_bits, batch_bits, budget, bm, bo, with_tiles,
+               kernel, stride, padding, extent)
 
     def build(fp):
         fault.check("plan")
         MAPSEARCH_CALLS[0] += 1
-        maps = mapsearch.build_maps_gconv3(coords, batch, valid,
-                                           grid_bits=grid_bits,
-                                           batch_bits=batch_bits,
-                                           out_budget=budget)
-        overflow = _require_out_capacity(maps.overflow, maps.n_true, budget)
-        kmap = mapsearch.strided_to_kmap(maps, n_out=budget, n_taps=27)
-        tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo) \
-            if with_tiles else None
-        return ConvPlan("gconv3", kmap, tiles, budget, 27,
+        with TraceAnnotation("plan.search"):
+            maps = mapsearch.build_maps_gconv3(
+                coords, batch, valid, grid_bits=grid_bits,
+                batch_bits=batch_bits, out_budget=budget, kernel=kernel,
+                stride=stride, padding=padding, extent=extent)
+        overflow, sites = _require_out_capacity(maps.overflow, maps.n_true,
+                                                budget)
+        with TraceAnnotation("plan.search"):
+            kmap = mapsearch.strided_to_kmap(maps, n_out=budget,
+                                             n_taps=n_taps)
+        tiles = None
+        if with_tiles:
+            with TraceAnnotation("plan.tiles"):
+                tiles = sg_ops.build_tap_tiles(kmap, None, bm=bm, bo=bo)
+        return ConvPlan("gconv3", kmap, tiles, budget, n_taps,
                         maps.out_coords, maps.out_batch, maps.out_valid, maps,
-                        overflow)
+                        overflow, sites)
 
     return _maybe_cached(cache, (coords, batch, valid), statics, build)
 

@@ -4,21 +4,27 @@ The "millions of users" integration layer over everything PRs 1-6
 built: requests enter through the bounded, bucket-quantizing
 :class:`~repro.runtime.admission.AdmissionQueue`, plans resolve through
 one long-lived content-addressed PlanCache (repeated scenes search
-zero extra times), and execution runs through
-``models.minkunet.forward_multicloud`` with a **per-bucket compiled
-executable**: plan arrays are threaded into the jitted forward as
-*traced arguments* over a static skeleton, so every request in a
-padding bucket replays one XLA executable — the engine compiles once
-per bucket class, never once per request geometry.
+zero extra times), and execution runs one request at a time through a
+**per-bucket compiled executable**: plan arrays are threaded into the
+jitted forward as *traced arguments* over a static skeleton, so every
+request in a padding bucket replays one XLA executable — the engine
+compiles once per bucket class, never once per request geometry.
+
+The engine serves any model in ``MODELS``, picked by the type of its
+config. A model module gives ``build_plans(coords, batch, valid, cfg,
+*, cache, n_max)`` (called by attribute per request), ``SERVE_STAGES``
+(its forward as one or more executables, each later one launched inside
+its own span; MinkUNet's is one, SECOND's two) and
+``plans_per_cloud(cfg)`` (the PlanCache's sizing).
 
 Robustness posture:
 
   * **Per-request fault isolation** — each request's plan build and
-    forward run under a retry-once guard (``forward_multicloud``'s
-    ``on_error`` hook): a transient fault (an injected one-shot, a
-    flaky lowering) recovers with the same impl and a bit-identical
-    result; a persistent one quarantines *that request only* with a
-    typed :data:`~repro.runtime.admission.ISOLATED_FAULT` outcome.
+    forward run under a retry-once guard: a transient fault (an
+    injected one-shot, a flaky lowering) recovers with the same impl
+    and a bit-identical result; a persistent one quarantines *that
+    request only* with a typed
+    :data:`~repro.runtime.admission.ISOLATED_FAULT` outcome.
     Batchmates' results stay bit-identical to a fault-free run —
     ``benchmarks/serve_replay.py`` gates on exactly this.
   * **Graceful-degradation ladder** driven by
@@ -42,6 +48,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import hashlib
 import time
 
@@ -52,8 +59,11 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import plan as planlib
 from repro.core.spconv import SparseTensor
-from repro.models import minkunet
+from repro.models import minkunet, second
 from repro.runtime import admission, fault, guard
+
+#: the served models, by the type of their config
+MODELS = {minkunet.MinkUNetConfig: minkunet, second.SECONDConfig: second}
 
 # ---------------------------------------------------------------------------
 # Plan splitting: traced arrays vs static skeleton
@@ -63,7 +73,7 @@ _ARRAY_TYPES = (jax.Array, np.ndarray)
 
 
 def split_plans(plans):
-    """Partition a :class:`~repro.models.minkunet.MinkPlans` pytree into
+    """Partition a model's plan pytree (``MinkPlans``, ``SECONDPlans``) into
     traced-array leaves and a hashable static skeleton.
 
     Returns ``(dyn, treedef, static, skeleton)``: ``dyn`` is the leaf
@@ -91,6 +101,20 @@ def merge_plans(treedef, static, dyn):
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
+def _staged(first, later):
+    """One request's forward as several executables: ``first`` over the
+    request's arrays, then each ``(span, fn)`` of ``later`` over the
+    previous output, launched inside its span. Nothing is read back in
+    between."""
+    def run(params, *args):
+        x = first(params, *args)
+        for span, fn in later:
+            with TraceAnnotation(span):
+                x = fn(params, x)
+        return x
+    return run
+
+
 # ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
@@ -105,8 +129,10 @@ class ServeResult:
     bucket: int | None = None
     latency_s: float | None = None   # submit -> result ready (completed)
     degraded: bool = False       # served while the ladder was engaged
-    digest: str | None = None    # sha256 of the logits bytes
-    logits: object = None        # np.ndarray for completed requests
+    digest: str | None = None    # sha256 of the answer's bytes
+    logits: object = None        # the model's answer, np.ndarray, for
+                                 # completed requests (MinkUNet: per-voxel
+                                 # logits; SECOND: the head maps)
 
 
 #: ladder levels (DESIGN.md §12): 0 healthy, 1 shrink batch, 2 ref
@@ -115,10 +141,12 @@ LADDER_MAX = 3
 
 
 class ServeEngine:
-    """Continuous-batching engine over MinkUNet + the admission queue.
+    """Continuous-batching engine over a served model + the admission
+    queue.
 
     Args:
-      params, model_cfg: the served model (init once, serve many).
+      params, model_cfg: the served model (init once, serve many); the
+        config's type picks the model module (``MODELS``).
       impl: primary rulebook-execution backend (None: resolved per host
         by ``spconv_gemm.ops.kernel_impl`` — the fused Pallas kernel on
         TPU, ``'ref'`` elsewhere). Ladder level 2 forces ``'ref'``, a
@@ -146,7 +174,7 @@ class ServeEngine:
     the serve gate asserts it.
     """
 
-    def __init__(self, params, model_cfg: minkunet.MinkUNetConfig, *,
+    def __init__(self, params, model_cfg, *,
                  impl: str | None = None,
                  queue: admission.AdmissionQueue | None = None,
                  max_batch: int | None = None, clock=time.monotonic,
@@ -155,6 +183,7 @@ class ServeEngine:
         import os
         self.params = params
         self.model_cfg = model_cfg
+        self.model = MODELS[type(model_cfg)]
         self.impl = impl
         self.clock = clock
         self.queue = queue if queue is not None else admission.AdmissionQueue(
@@ -173,8 +202,7 @@ class ServeEngine:
                 os.path.join(persist_dir, "journal"))
             pinned = feature_cache.PinnedStore(persist=self.persist)
         self.cache = planlib.PlanCache(
-            capacity=max(64, 8 * (2 * (len(model_cfg.enc)
-                                       + len(model_cfg.dec)) + 2)),
+            capacity=max(64, 8 * self.model.plans_per_cloud(model_cfg)),
             verify=verify_cache, persist=self.persist, pinned=pinned)
         self.recover_after = recover_after
         self.level = 0
@@ -264,14 +292,21 @@ class ServeEngine:
         fn = self._exec.get(key)
         if fn is not None:
             return fn
-        cfg = self.model_cfg
+        cfg, model = self.model_cfg, self.model
+        (_, first), *later = model.SERVE_STAGES
 
         @jax.jit
         def run(params, coords, batch, valid, feats, dyn):
             plans = merge_plans(treedef, static, dyn)
             st = SparseTensor(coords, batch, valid, feats)
-            return minkunet.forward(params, st, cfg, plans=plans, impl=impl)
+            return getattr(model, first)(params, st, cfg, plans=plans,
+                                         impl=impl)
 
+        if later:
+            run = _staged(run, [
+                (span, jax.jit(functools.partial(getattr(model, name),
+                                                 cfg=cfg)))
+                for span, name in later])
         self._exec[key] = run
         self.compiled += 1
         guard.health().note("serve.compile")
@@ -375,9 +410,9 @@ class ServeEngine:
                 b = jnp.asarray(req.batch)
                 v = jnp.asarray(req.valid)
                 f = jnp.asarray(req.feats)
-                plans = minkunet.build_plans(c, b, v, self.model_cfg,
-                                             cache=self.cache,
-                                             n_max=req.bucket)
+                plans = self.model.build_plans(c, b, v, self.model_cfg,
+                                               cache=self.cache,
+                                               n_max=req.bucket)
                 return SparseTensor(c, b, v, f), plans
 
         for i, req in enumerate(reqs):
@@ -404,10 +439,12 @@ class ServeEngine:
                 results[i] = self._isolate(reqs[i], exc)
                 return None
 
-        outs = minkunet.forward_multicloud(
-            self.params, [sts[i] for i in live], self.model_cfg,
-            cache=self.cache, plans=[built[i] for i in live],
-            forward_fn=self._forward_fn, on_error=on_error)
+        outs = []
+        for j, i in enumerate(live):
+            try:
+                outs.append(self._forward_fn(self.params, sts[i], built[i]))
+            except Exception as e:                   # noqa: BLE001
+                outs.append(on_error(j, e))
 
         for j, i in enumerate(live):
             if results[i] is not None:

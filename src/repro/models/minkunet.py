@@ -259,11 +259,19 @@ def forward(params, st: SparseTensor, cfg: MinkUNetConfig, *,
         return jnp.where(st.valid[:, None], logits, 0)
 
 
+#: the served forward as executables (launch/spconv_serve.py): one
+SERVE_STAGES = ((None, "forward"),)
+
+
+def plans_per_cloud(cfg: MinkUNetConfig) -> int:
+    """Plans one fresh cloud adds to a PlanCache (with room to spare)."""
+    return 2 * (len(cfg.enc) + len(cfg.dec)) + 2
+
+
 def forward_multicloud(params, clouds, cfg: MinkUNetConfig, *,
                        training: bool = False,
                        cache: planlib.PlanCache | None = None,
-                       impl: str | None = None,
-                       plans=None, forward_fn=None, on_error=None) -> list:
+                       impl: str | None = None) -> list:
     """Batched multi-cloud inference: per-voxel logits for each cloud.
 
     Serving-scale entry point: run it under an active device mesh and
@@ -277,41 +285,15 @@ def forward_multicloud(params, clouds, cfg: MinkUNetConfig, *,
     repeated clouds across requests: a client re-sending the same scene
     (or the same cloud appearing twice in one batch) hits by content
     even though every buffer is new. The cache is sized so no cloud
-    evicts another's stage plans mid-pass.
-
-    The serving engine (launch/spconv_serve.py, DESIGN.md §12) drives
-    this with all three hooks:
-
-      * ``plans`` — per-cloud prebuilt :class:`MinkPlans` (aligned with
-        ``clouds``); plan build then happens eagerly at admission, and
-        the forward performs no lookups.
-      * ``forward_fn`` — ``(params, st, plans_i) -> logits`` override,
-        the engine's per-bucket *compiled* executable (plans threaded as
-        traced arguments, one trace per padding-bucket class).
-      * ``on_error`` — ``(index, exc) -> result`` per-request fault
-        isolation: an exception while executing cloud *i* is routed
-        here (retry / quarantine / placeholder) instead of aborting the
-        batchmates. None keeps the raising behavior.
+    evicts another's stage plans mid-pass. (The serving engine,
+    launch/spconv_serve.py, runs its own per-cloud loop over prebuilt
+    plans and compiled executables.)
     """
     if cache is None:
-        per_cloud = 2 * (len(cfg.enc) + len(cfg.dec)) + 2
-        cache = planlib.PlanCache(capacity=max(64, per_cloud * len(clouds)))
-    out = []
-    for i, st in enumerate(clouds):
-        try:
-            if forward_fn is not None:
-                r = forward_fn(params, st,
-                               plans[i] if plans is not None else None)
-            else:
-                r = forward(params, st, cfg, training=training, cache=cache,
-                            impl=impl,
-                            plans=plans[i] if plans is not None else None)
-        except Exception as e:                       # noqa: BLE001
-            if on_error is None:
-                raise
-            r = on_error(i, e)
-        out.append(r)
-    return out
+        cache = planlib.PlanCache(
+            capacity=max(64, plans_per_cloud(cfg) * len(clouds)))
+    return [forward(params, st, cfg, training=training, cache=cache,
+                    impl=impl) for st in clouds]
 
 
 def segmentation_loss(params, batch, cfg: MinkUNetConfig, *,

@@ -54,25 +54,53 @@ def test_minkunet_learns_on_synthetic_segmentation():
 
 
 def test_second_detection_pipeline():
-    cfg = second.SECONDConfig(channels=(8, 8, 16), blocks=1, bev_hw=32,
-                              bev_z=4, head_ch=16, n_batch=2)
+    cfg = second.SECONDConfig(
+        name="second-tiny", sparse_shape=(32, 32, 41), stem=(8,),
+        stages=((8, (3, 3, 3), (2, 2, 2), (1, 1, 1), 1),
+                (8, (3, 3, 3), (2, 2, 2), (1, 1, 1), 1),
+                (16, (3, 3, 3), (2, 2, 2), (1, 1, 0), 1),
+                (16, (1, 1, 3), (1, 1, 2), (0, 0, 0), 0)),
+        bev_layers=(1, 1), bev_filters=(16, 16), up_filters=(16, 16))
     params = second.init_model(cfg, jax.random.key(1))
-    batch = _batch("lidar", 1024, nb=2)
-    batch["objectness"] = jnp.zeros((2, 32, 32)).at[:, 8:10, 8:10].set(1.0)
-    batch["boxes"] = jnp.zeros((2, 32, 32, 7))
-    loss, metrics = jax.jit(
-        lambda p: second.detection_loss(p, batch, cfg))(params)
+    # two clouds on one grid, so their BEV cells coincide
+    rng = np.random.default_rng(0)
+    x, y = np.meshgrid(np.arange(32), np.arange(32), indexing="ij")
+    coords, ids = [], []
+    for b in range(2):
+        keep = rng.random(x.shape) < 0.35
+        coords.append(np.stack([x[keep], y[keep],
+                                10 + (x[keep] + y[keep]) // 12], 1))
+        ids.append(np.full(keep.sum(), b))
+    coords, ids = np.concatenate(coords), np.concatenate(ids)
+    n, rows = coords.shape[0], 768
+    batch = {"coords": jnp.zeros((rows, 3), jnp.int32).at[:n].set(coords),
+             "batch": jnp.zeros((rows,), jnp.int32).at[:n].set(ids),
+             "valid": jnp.arange(rows) < n,
+             "feats": jnp.zeros((rows, 4)).at[:n].set(
+                 rng.normal(size=(n, 4)))}
+    h, w, _ = second.bev_shape(cfg)
+    widths = second.head_widths(cfg)
+    batch["objectness"] = jnp.zeros((2 * h * w, widths["cls"])).at[
+        5:7].set(1.0)
+    batch["boxes"] = jnp.zeros((2 * h * w, widths["box"]))
+    st = second.SparseTensor(batch["coords"], batch["batch"], batch["valid"],
+                             batch["feats"])
+
+    @jax.jit
+    def step(p):
+        (loss, _), grads = jax.value_and_grad(
+            lambda q: second.detection_loss(q, batch, cfg, n_batch=2),
+            has_aux=True)(p)
+        # BEV densification preserves mass: no cloud's voxel lands on
+        # another's
+        mid = second.backbone(p, st, cfg, plans=second.build_plans(
+            st.coords, st.batch, st.valid, cfg, replan=False))
+        mass = jnp.where(mid.valid[:, None], mid.feats, 0).sum()
+        return loss, grads, mass, second.to_bev(mid, cfg, n_batch=2).sum()
+
+    loss, grads, mass, bev_mass = step(params)
     assert np.isfinite(float(loss))
-    grads = jax.jit(jax.grad(
-        lambda p: second.detection_loss(p, batch, cfg)[0]))(params)
     assert all(np.isfinite(np.asarray(g)).all()
                for g in jax.tree.leaves(grads))
-    # BEV densification preserves mass: sum of valid features == sum of BEV
-    mid = second.middle_extractor(params, second.SparseTensor(
-        batch["coords"], batch["batch"], batch["valid"], batch["feats"]),
-        cfg)
-    bev = second.to_bev(mid, cfg)
-    np.testing.assert_allclose(
-        float(jnp.where(mid.valid[:, None], mid.feats, 0)
-              .astype(jnp.float32).sum()),
-        float(bev.astype(jnp.float32).sum()), rtol=1e-3)
+    assert float(mass) > 0
+    np.testing.assert_allclose(float(mass), float(bev_mass), rtol=1e-5)
