@@ -14,7 +14,8 @@ What is cacheable and what is not (DESIGN.md §4, §10):
 
   * kmap / tiles / tap schedule   — geometry-only, cached.
   * SPAC liveness (tile_nz)       — depends on the post-ReLU zero pattern of
-    the *current* features, refreshed per layer by ops.tile_liveness.
+    the *current* features, refreshed per layer by ops.refresh_liveness
+    (a no-op check when every row a valid map reads is live).
 
 Cache keys come in two forms (DESIGN.md §10):
 
@@ -766,7 +767,8 @@ def execute(plan: ConvPlan, feats: jnp.ndarray, weights: jnp.ndarray,
             bias: jnp.ndarray | None = None, *, spac: bool = True,
             act: "sparsity.ActSparsity | None" = None,
             epilogue: "sg_ops.FusedEpilogue | None" = None,
-            impl: str | None = None, bn: int = 128):
+            impl: str | None = None, bn: int = 128,
+            src_valid: jnp.ndarray | None = None):
     """Run rulebook execution for ``plan`` over the current features.
 
     ``feats`` / ``weights`` / ``bias`` are stream-tier by design
@@ -785,6 +787,11 @@ def execute(plan: ConvPlan, feats: jnp.ndarray, weights: jnp.ndarray,
     ``(out, ActSparsity)`` — inference-only, see sg_ops.FusedEpilogue.
     SPAC elision (any grain) is forward-only lossless: every path here
     differentiates through the un-elided geometry math (DESIGN.md §2).
+
+    ``src_valid`` is the (N,) mask of the rows a valid map may read, for
+    a Subm3 plan the ``valid`` it was built from: with SPAC on, the
+    liveness refresh then short-circuits when every such row is live
+    (sg_ops.refresh_liveness, DESIGN.md §14).
     """
     impl = impl or sg_ops.kernel_impl()
     if impl == "xla":
@@ -816,4 +823,4 @@ def execute(plan: ConvPlan, feats: jnp.ndarray, weights: jnp.ndarray,
     return sg_ops.apply_tiles(feats, weights, plan.tiles, bias,
                               n_out=plan.n_out, row_nz=row_nz,
                               act=act if spac else None, epilogue=epilogue,
-                              bn=bn, impl=impl)
+                              bn=bn, impl=impl, src_valid=src_valid)

@@ -21,15 +21,45 @@ core/rulebook.py implement the rule).
 
 :class:`ActSparsity` threads the post-ReLU zero pattern from one layer's
 fused epilogue into the next layer's masks without re-sweeping the feature
-array in HBM. :func:`sparsity_stats` quantifies the grains against the
-paper's element grain so the granularity loss is measurable
-(EXPERIMENTS.md §Paper-fidelity).
+array in HBM. :func:`refresh_log` counts the liveness refreshes of a
+traced forward that could not short-circuit. :func:`sparsity_stats`
+quantifies the grains against the paper's element grain so the
+granularity loss is measurable (EXPERIMENTS.md §Paper-fidelity).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple
 
 import jax.numpy as jnp
+
+#: open refresh logs, innermost last (see :func:`refresh_log`)
+_REFRESH_LOGS: list[list] = []
+
+
+@contextlib.contextmanager
+def refresh_log():
+    """Collect the "full" flag of every SPAC liveness refresh made inside.
+
+    ``ops.refresh_liveness`` appends one int32 scalar per refresh to the
+    innermost open log: 1 where it ran the full per-slot gather, 0 where
+    it short-circuited to the geometry flags. Open it around the tracing
+    of a forward and return ``sum(log)`` next to the answer; ``len(log)``
+    is then the static number of refreshes per call (the serve engine's
+    ``spac.refresh`` / ``spac.refresh_full`` counters).
+    """
+    log: list = []
+    _REFRESH_LOGS.append(log)
+    try:
+        yield log
+    finally:
+        _REFRESH_LOGS.pop()
+
+
+def note_refresh(full) -> None:
+    """Record one refresh's "full" flag in the innermost open log."""
+    if _REFRESH_LOGS:
+        _REFRESH_LOGS[-1].append(jnp.asarray(full, jnp.int32))
 
 
 def row_nonzero(feats: jnp.ndarray) -> jnp.ndarray:

@@ -114,7 +114,7 @@ def subm_conv3(st: SparseTensor, params: dict, *, max_blocks: int,
                                   bm=bm, bo=bo, search_impl=search_impl,
                                   cache=cache)
     out = planlib.execute(plan, st.feats, params["w"], params["b"],
-                          spac=spac, act=act, impl=impl)
+                          spac=spac, act=act, impl=impl, src_valid=st.valid)
     out = jnp.where(st.valid[:, None], out, 0)
     return st.replace_feats(out)
 
@@ -167,7 +167,7 @@ def subm_conv3_bn_relu(st: SparseTensor, conv_params: dict, bn_params: dict,
     epi = sg_ops.FusedEpilogue(scale=scale, shift=shift, valid=st.valid)
     out, out_act = planlib.execute(plan, st.feats, conv_params["w"], None,
                                    spac=spac, act=act, epilogue=epi,
-                                   impl=impl)
+                                   impl=impl, src_valid=st.valid)
     return st.replace_feats(out), out_act
 
 

@@ -361,6 +361,44 @@ def tile_block_liveness(tiles: TapTiles, blk_nz: jnp.ndarray) -> jnp.ndarray:
         jnp.int32)
 
 
+def refresh_liveness(tiles: TapTiles, row_nz: jnp.ndarray,
+                     blk_nz: jnp.ndarray | None = None, *, n_k: int = 1,
+                     src_valid: jnp.ndarray | None = None):
+    """``(tile_nz, tile_bk_nz)``: the SPAC skip flags of one layer.
+
+    The refresh (:func:`tile_liveness`, and :func:`tile_block_liveness`
+    when ``blk_nz`` is given) gathers a liveness bit for every map slot.
+    It can differ from the geometry flags the tiles already hold
+    (``tiles.tile_nz``) only where a valid slot reads a dead row. With
+    ``src_valid``, the (N,) mask of the rows a valid slot may read (for a
+    Subm3 plan the ``valid`` it was built from: its search table holds
+    only valid voxels), one O(N) reduction checks that every such row is
+    live, and ``lax.cond`` then takes the geometry flags and skips the
+    gather. Both branches give the same flags whenever the check holds,
+    so the kernel's work and the answer do not change; a dead valid row
+    still takes the full refresh. Each call notes its "full" flag with
+    :func:`repro.core.sparsity.note_refresh`.
+    """
+    def full():
+        tile_nz = tile_liveness(tiles, row_nz)
+        if blk_nz is None:
+            return tile_nz, jnp.repeat(tile_nz[:, None], n_k, axis=1)
+        return tile_nz, tile_block_liveness(tiles, blk_nz)
+
+    if src_valid is None:
+        _sparsity.note_refresh(True)
+        return full()
+
+    def geometry():
+        return tiles.tile_nz, jnp.repeat(tiles.tile_nz[:, None], n_k, axis=1)
+
+    # blk_nz is ANDed with row_nz, so its check implies the row check
+    all_live = (jnp.all(row_nz | ~src_valid) if blk_nz is None
+                else jnp.all(blk_nz | ~src_valid[:, None]))
+    _sparsity.note_refresh(~all_live)
+    return jax.lax.cond(all_live, geometry, full)
+
+
 def pick_bk(c_in: int, *, bm: int, bn: int, bo: int, c_out: int,
             budget_bytes: int = VMEM_BUDGET_BYTES) -> int:
     """Largest Cin block dividing ``c_in`` that keeps the fused kernel's
@@ -570,7 +608,8 @@ def apply_tiles(feats: jnp.ndarray, weights: jnp.ndarray, tiles: TapTiles,
                 row_nz: jnp.ndarray | None = None,
                 act: "_sparsity.ActSparsity | None" = None,
                 epilogue: FusedEpilogue | None = None, bn: int = 128,
-                bk: int | None = None, impl: str | None = None):
+                bk: int | None = None, impl: str | None = None,
+                src_valid: jnp.ndarray | None = None):
     """Execute a rulebook from pre-built tiles (the ConvPlan hot path).
 
     feats stays un-gathered; the output-stationary fused kernel (or its
@@ -583,7 +622,20 @@ def apply_tiles(feats: jnp.ndarray, weights: jnp.ndarray, tiles: TapTiles,
     engages whenever liveness is available and ``REPRO_SPAC_BLOCK`` is on.
     C_out is zero-padded to a bn multiple for the kernel and sliced back
     afterwards; the Cin block ``bk`` is picked from the DESIGN.md §6 VMEM
-    budget unless given. Differentiable under every impl — the custom VJP
+    budget unless given.
+
+    ``src_valid`` (N,) bool marks the rows a valid slot may read; every
+    ``kmap`` entry >= 0 must point at one (true of a Subm3 plan and its
+    ``valid``). With it the refresh short-circuits to the geometry flags
+    when every such row is live (:func:`refresh_liveness`): one O(N)
+    check in place of a gather over all M_pad slots, exact because a
+    refresh can only clear a tile whose valid slots all read dead rows.
+    A dead valid row, or no ``src_valid``, takes the full refresh. With
+    trained weights that leave a few dead rows, the follow-up is a
+    refresh bounded to those rows' slots through a row-to-slot inverse
+    on the plan.
+
+    Differentiable under every impl — the custom VJP
     re-derives the gradient through the *un-elided* XLA oracle math, so
     SPAC stays forward-only (DESIGN.md §2).
 
@@ -623,7 +675,6 @@ def apply_tiles(feats: jnp.ndarray, weights: jnp.ndarray, tiles: TapTiles,
         tile_nz = tile_nz_geo
         tile_bk_nz = jnp.repeat(tile_nz[:, None], n_k, axis=1)
     else:
-        tile_nz = tile_liveness(tiles, row_nz)
         blk_nz = None
         if n_k > 1 and spac_block_enabled():
             if act is not None:
@@ -633,10 +684,8 @@ def apply_tiles(feats: jnp.ndarray, weights: jnp.ndarray, tiles: TapTiles,
             # keep block liveness consistent with the (possibly coarser)
             # row mask: a live block must never outlive its tile
             blk_nz = blk_nz & row_nz[:, None]
-        if blk_nz is None:
-            tile_bk_nz = jnp.repeat(tile_nz[:, None], n_k, axis=1)
-        else:
-            tile_bk_nz = tile_block_liveness(tiles, blk_nz)
+        tile_nz, tile_bk_nz = refresh_liveness(tiles, row_nz, blk_nz,
+                                               n_k=n_k, src_valid=src_valid)
     n_out_pad = -(-n_out // bo) * bo
 
     if epilogue is not None:

@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
-from repro.core import plan as planlib
+from repro.core import plan as planlib, sparsity
 from repro.core.spconv import SparseTensor
 from repro.models import minkunet, second
 from repro.runtime import admission, fault, guard
@@ -103,15 +103,15 @@ def merge_plans(treedef, static, dyn):
 
 def _staged(first, later):
     """One request's forward as several executables: ``first`` over the
-    request's arrays, then each ``(span, fn)`` of ``later`` over the
-    previous output, launched inside its span. Nothing is read back in
-    between."""
+    request's arrays, returning ``(x, extra)``, then each ``(span, fn)``
+    of ``later`` over the previous ``x``, launched inside its span.
+    Returns ``(answer, extra)``. Nothing is read back in between."""
     def run(params, *args):
-        x = first(params, *args)
+        x, extra = first(params, *args)
         for span, fn in later:
             with TraceAnnotation(span):
                 x = fn(params, x)
-        return x
+        return x, extra
     return run
 
 
@@ -208,6 +208,8 @@ class ServeEngine:
         self.level = 0
         self._healthy_ticks = 0
         self._exec: dict = {}            # skeleton -> jitted executable
+        self._refreshes: dict = {}       # skeleton -> SPAC refreshes/cloud
+        self._last_refresh = None        # set by _forward_fn
         self.compiled = 0
         self._ewma: dict[int, float] = {}    # bucket -> service seconds
         self.results: list[ServeResult] = []
@@ -294,13 +296,17 @@ class ServeEngine:
             return fn
         cfg, model = self.model_cfg, self.model
         (_, first), *later = model.SERVE_STAGES
+        refreshes = self._refreshes
 
         @jax.jit
         def run(params, coords, batch, valid, feats, dyn):
             plans = merge_plans(treedef, static, dyn)
             st = SparseTensor(coords, batch, valid, feats)
-            return getattr(model, first)(params, st, cfg, plans=plans,
-                                         impl=impl)
+            with sparsity.refresh_log() as log:
+                out = getattr(model, first)(params, st, cfg, plans=plans,
+                                            impl=impl)
+            refreshes[key] = len(log)         # static: noted while tracing
+            return out, sum(log, jnp.zeros((), jnp.int32))
 
         if later:
             run = _staged(run, [
@@ -313,11 +319,18 @@ class ServeEngine:
         return run
 
     def _forward_fn(self, params, st: SparseTensor, plans):
+        """Launch one request's forward; returns the answer alone. The
+        executable's count of full SPAC refreshes is left, unread, in
+        ``self._last_refresh`` as ``(refreshes per cloud, full count)``
+        for ``serve.fetch`` to read after the answer."""
         with TraceAnnotation("serve.dispatch"):
             dyn, treedef, static, skeleton = split_plans(plans)
-            fn = self._executable(skeleton, treedef, static,
-                                  self._impl_now())
-            return fn(params, st.coords, st.batch, st.valid, st.feats, dyn)
+            impl = self._impl_now()
+            fn = self._executable(skeleton, treedef, static, impl)
+            out, full = fn(params, st.coords, st.batch, st.valid, st.feats,
+                           dyn)
+            self._last_refresh = (self._refreshes[(skeleton, impl)], full)
+            return out
 
     # -- the continuous-batching tick ----------------------------------------
 
@@ -439,12 +452,14 @@ class ServeEngine:
                 results[i] = self._isolate(reqs[i], exc)
                 return None
 
-        outs = []
+        outs, refreshes = [], []
         for j, i in enumerate(live):
+            self._last_refresh = None
             try:
                 outs.append(self._forward_fn(self.params, sts[i], built[i]))
             except Exception as e:                   # noqa: BLE001
                 outs.append(on_error(j, e))
+            refreshes.append(self._last_refresh)
 
         for j, i in enumerate(live):
             if results[i] is not None:
@@ -452,6 +467,10 @@ class ServeEngine:
             req = reqs[i]
             with TraceAnnotation("serve.fetch", rid=req.rid):
                 logits = np.asarray(outs[j])
+                if refreshes[j] is not None:
+                    n, full = refreshes[j]
+                    guard.health().note("spac.refresh", n)
+                    guard.health().note("spac.refresh_full", int(full))
             with TraceAnnotation("serve.finish", rid=req.rid):
                 done = self.clock()
                 self._note_service(req.bucket, done - req.submitted_at)

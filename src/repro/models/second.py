@@ -234,7 +234,7 @@ def backbone(params, st: SparseTensor, cfg: SECONDConfig, *,
 
     def conv(st, layer, plan, spac):
         y = planlib.execute(plan, st.feats, layer["w"], None, spac=spac,
-                            impl=impl)
+                            impl=impl, src_valid=st.valid)
         if plan.out_coords is not None:
             st = SparseTensor(plan.out_coords, plan.out_batch,
                               plan.out_valid, y)

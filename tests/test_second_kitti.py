@@ -2,6 +2,8 @@
 strided maps against brute force, HeightCompression's channel order, a
 tiny preset through ``ServeEngine`` against the plain float32 reference,
 and MinkUNet's served answers unchanged by the engine's model interface."""
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -218,3 +220,90 @@ def test_minkunet_served_answer_is_its_forward():
 
     want = np.asarray(forward(params, *cj, dyn))
     np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# The SPAC refresh's short-circuit on the served path
+# ---------------------------------------------------------------------------
+
+#: MinkUNet and SECOND narrow enough for the CPU, and at least 16 channels
+#: wide after every BN + ReLU, so that a row of random-weight features is
+#: all zero with odds of about 2^-16 or less
+SPAC_MODELS = {
+    "minkunet": minkunet.MinkUNetConfig(stem=32, enc=(32, 32),
+                                        dec=(32, 32), classes=4, blocks=1),
+    "second": dataclasses.replace(TINY, stem=(16, 16), stages=tuple(
+        (max(c, 16), *rest) for c, *rest in TINY.stages)),
+}
+
+
+def _subm3_convs(cfg):
+    if isinstance(cfg, minkunet.MinkUNetConfig):
+        return 1 + cfg.blocks * (len(cfg.enc) + len(cfg.dec))
+    return len(cfg.stem) + sum(blocks for *_, blocks in cfg.stages)
+
+
+def _init(cfg):
+    model = minkunet if isinstance(cfg, minkunet.MinkUNetConfig) else second
+    return model, jax.jit(lambda k: model.init_model(cfg, k))(
+        jax.random.key(8))
+
+
+@pytest.mark.parametrize("name", list(SPAC_MODELS))
+def test_subm3_kmap_reads_only_valid_rows(name):
+    """Every kmap entry >= 0 of a Subm3 plan points at a row whose
+    ``valid`` bit the plan was built from is set, so a liveness check
+    over valid rows covers every row a valid slot reads. Padding rows
+    repeat valid coordinates, so a search that indexed them would
+    show."""
+    cfg = SPAC_MODELS[name]
+    model, _ = _init(cfg)
+    c, f = surface(9)
+    coords, batch, valid, _ = padded(c, f)
+    coords[c.shape[0]:] = c[:ROWS - c.shape[0]]
+    plans = model.build_plans(jnp.asarray(coords), jnp.asarray(batch),
+                              jnp.asarray(valid), cfg)
+    masks = [valid] + [np.asarray(d.out_valid) for d in plans.down]
+    subms = [(p, m) for p, m in zip(plans.subm, masks) if p is not None]
+    assert len(subms) == len(masks) - (name == "second")
+    for plan, mask in subms:
+        kmap = np.asarray(plan.kmap)
+        assert (kmap >= 0).any() and (~mask).any()
+        assert mask[kmap[kmap >= 0]].all()
+
+
+@pytest.mark.parametrize("name", list(SPAC_MODELS))
+def test_engine_counts_spac_refreshes(name, monkeypatch):
+    """``spac.refresh`` counts every Subm3 conv of every served cloud and
+    ``spac.refresh_full`` none of them on ordinary features. A cloud with
+    one all-zero voxel row at the stem's input takes the full refresh at
+    least once and is answered bit for bit as by the path without the
+    short-circuit."""
+    cfg = SPAC_MODELS[name]
+    _, params = _init(cfg)
+    convs = _subm3_convs(cfg)
+    engine = engine_for(params, cfg)
+    with guard.scoped_health() as h:
+        for seed in (5, 6):
+            serve_one(engine, padded(*surface(seed)))
+        assert h.get("spac.refresh") == 2 * convs
+        assert h.get("spac.refresh_full") == 0
+
+    c, f = surface(7)
+    f[3] = 0.0
+    with guard.scoped_health() as h:
+        engine.submit("r", *padded(c, f), deadline_s=600.0)
+        (got,) = engine.step()
+        assert h.get("spac.refresh") == convs
+        assert h.get("spac.refresh_full") >= 1
+
+    execute = planlib.execute
+    monkeypatch.setattr(planlib, "execute",
+                        lambda *a, src_valid=None, **k: execute(*a, **k))
+    with guard.scoped_health() as h:
+        engine = engine_for(params, cfg)
+        engine.submit("r", *padded(c, f), deadline_s=600.0)
+        (want,) = engine.step()
+        assert h.get("spac.refresh_full") == convs
+    assert got.status == want.status == "completed"
+    assert got.digest == want.digest

@@ -20,6 +20,7 @@ import os
 
 import numpy as np
 import jax
+import jax.extend
 import jax.numpy as jnp
 import pytest
 
@@ -176,6 +177,113 @@ def test_spac_block_flag_off_still_lossless():
         del os.environ["REPRO_SPAC_BLOCK"]
     assert bool((on == off).all())
     assert bool((on2 == off).all())
+
+
+# ---------------------------------------------------------------------------
+# The liveness refresh short-circuits only where it is exact
+# ---------------------------------------------------------------------------
+
+#: case -> (bk, whether the refresh must run in full)
+REFRESH_CASES = {
+    "no_dead_row": (None, False),
+    "one_dead_valid_row": (None, True),
+    "every_row_dead": (None, True),
+    "dead_padding_only": (None, False),
+    "block_grain_live": (16, False),
+    "block_grain_dead_block": (16, True),
+}
+
+
+def _refresh_case(case):
+    rng = np.random.default_rng(11)
+    n, cin, cout = 48, 32, 8
+    coords, bidx, valid = random_cloud(rng, n, extent=6, batch=2,
+                                       n_valid=36)
+    feats = rng.standard_normal((n, cin)).astype(np.float32)
+    if case == "one_dead_valid_row":
+        feats[5] = 0.0
+    elif case == "every_row_dead":
+        feats[:] = 0.0
+    elif case in ("dead_padding_only", "block_grain_live"):
+        feats[~valid] = 0.0
+    elif case == "block_grain_dead_block":
+        feats[~valid] = 0.0
+        feats[1:, 16:] = 0.0          # rows live, upper blocks dead
+    st = SparseTensor(jnp.asarray(coords), jnp.asarray(bidx),
+                      jnp.asarray(valid), jnp.asarray(feats))
+    w = jnp.asarray(rng.standard_normal((27, cin, cout)).astype(np.float32))
+    plan = planlib.subm3_plan(st.coords, st.batch, st.valid, max_blocks=n,
+                              bm=BM)
+    return st, w, plan
+
+
+@pytest.mark.parametrize("impl", list(dict.fromkeys(("ref", KIMPL))))
+@pytest.mark.parametrize("case", list(REFRESH_CASES))
+def test_refresh_short_circuit_is_exact(case, impl):
+    """apply_tiles with ``src_valid`` is element-equal to the path without
+    it, and refresh_liveness gives exactly the flags of tile_liveness /
+    tile_block_liveness, whichever branch the check takes."""
+    bk, want_full = REFRESH_CASES[case]
+    st, w, plan = _refresh_case(case)
+    row_nz = sparsity.row_nonzero(st.feats)
+    with sparsity.refresh_log() as log:
+        got = sg_ops.apply_tiles(st.feats, w, plan.tiles, n_out=plan.n_out,
+                                 row_nz=row_nz, bk=bk, impl=impl,
+                                 src_valid=st.valid)
+    assert [int(f) for f in log] == [int(want_full)]
+    want = sg_ops.apply_tiles(st.feats, w, plan.tiles, n_out=plan.n_out,
+                              row_nz=row_nz, bk=bk, impl=impl)
+    assert bool((got == want).all()), case
+
+    tiles = plan.tiles
+    blk_nz = None if bk is None else (
+        sparsity.row_block_nonzero(st.feats, bk) & row_nz[:, None])
+    n_k = 1 if bk is None else st.feats.shape[1] // bk
+    tile_nz, tile_bk_nz = sg_ops.refresh_liveness(
+        tiles, row_nz, blk_nz, n_k=n_k, src_valid=st.valid)
+    ref_nz = sg_ops.tile_liveness(tiles, row_nz)
+    ref_bk = (jnp.repeat(ref_nz[:, None], n_k, axis=1) if blk_nz is None
+              else sg_ops.tile_block_liveness(tiles, blk_nz))
+    np.testing.assert_array_equal(np.asarray(tile_nz), np.asarray(ref_nz))
+    np.testing.assert_array_equal(np.asarray(tile_bk_nz), np.asarray(ref_bk))
+    # the full refresh must find something the geometry flags miss in
+    # the cases that need it, else the case would not test the branch
+    geo = np.asarray(tiles.tile_nz)
+    if case == "every_row_dead":
+        assert not np.asarray(ref_nz).any() and geo.any()
+    if case == "block_grain_dead_block":
+        assert (np.asarray(ref_bk) != geo[:, None]).any()
+
+
+def _eqns(jaxpr, in_cond=False):
+    """Every equation of ``jaxpr`` and of the jaxprs in its params, with
+    whether it sits inside a ``cond`` branch."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_cond
+        inner = in_cond or eqn.primitive.name == "cond"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub, inner)
+
+
+def test_refresh_is_a_cond_not_a_select():
+    """In a jitted Subm3 ``plan.execute`` with ``src_valid`` the per-slot
+    liveness gather sits in a ``cond`` branch alone: nothing outside
+    computes it, so no select of both liveness results can exist."""
+    st, w, plan = _refresh_case("no_dead_row")
+    fn = jax.jit(lambda f, v: planlib.execute(plan, f, w, impl="ref",
+                                              src_valid=v))
+    jaxpr = jax.make_jaxpr(fn)(st.feats, st.valid).jaxpr
+    eqns = list(_eqns(jaxpr))
+    assert sum(e.primitive.name == "cond" for e, _ in eqns) == 1
+
+    def bool_gather(e):
+        return (e.primitive.name == "gather"
+                and e.outvars[0].aval.dtype == jnp.bool_)
+
+    assert [inside for e, inside in eqns if bool_gather(e)] == [True]
 
 
 # ---------------------------------------------------------------------------
